@@ -576,4 +576,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

@@ -184,4 +184,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     raise SystemExit(main())

@@ -188,4 +188,6 @@ def bench() -> List[Row]:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

@@ -43,10 +43,8 @@ def main():
         c, d, small, y = application_code(a, b)
         print(s.report())
         reuse = s.runtime.mean_buffer_reuse()
-    ms = scilib.memspace.active()
     print(f"\nresult tier: {scilib.memspace.tier_of(c)} "
-          f"(memory kind {ms.kind_of(scilib.memspace.tier_of(c))}"
-          f"{', simulated' if ms.simulated else ''})")
+          f"({scilib.memspace.active().describe()})")
     print(f"mean buffer reuse: {reuse:.1f}")
     # verify against plain execution (the session is closed: these run
     # through the original, un-intercepted symbols)
@@ -58,4 +56,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

@@ -83,12 +83,24 @@ def production_trace(atoms_per_node: int = PROD["atoms_per_node"],
 # ----------------------------------------------------------------------- #
 # runnable mini-app (real numerics through the interception layer)         #
 # ----------------------------------------------------------------------- #
+def kkr_matrix(tg, z: complex):
+    """The KKR matrix ``M = I - z t G`` in ``tg``'s dtype.
+
+    The energy enters as a scalar of that dtype: under x64 a Python
+    complex would reach the eager multiply as a complex128 operand, which
+    a TPU's compiler cannot lower (it aborts the process).
+    """
+    import jax.numpy as jnp
+    return jnp.eye(tg.shape[0], dtype=tg.dtype) - np.asarray(z, tg.dtype) * tg
+
+
 def run_mini(atoms: int = 4, energies: int = 4, scf: int = 2,
              n: int = 192, nb: int = 64, seed: int = 0,
              dtype="complex128") -> Dict[str, float]:
     """Tiny LSMS: real KKR-style solves with verification.
 
-    Returns the total energy proxy and residual so tests can assert the
+    Returns the total energy proxy, the largest residual and the largest
+    LAPACK-style scaled residual (``max_ratio``) so tests can assert the
     physics loop is numerically sound under every offload policy.
     ``nb`` is kept for callers, but when the solver tier is patched the
     blocked LU takes its block size from the session's ``lapack_nb``
@@ -103,11 +115,13 @@ def run_mini(atoms: int = 4, energies: int = 4, scf: int = 2,
     rng = np.random.default_rng(seed)
     # structure "constants" G per atom: fixed across SCF; host-first-
     # touched like Fortran allocations, reused across all solves
-    gmats = [host_array(jnp.asarray(
+    gmats = [host_array(np.asarray(
         (rng.standard_normal((n, n)) + 1j * rng.standard_normal((n, n)))
         / (2 * n), dtype)) for _ in range(atoms)]
+    eps = float(np.finfo(dtype).eps)
     energy = 0.0
     max_resid = 0.0
+    max_ratio = 0.0
     n_solves = 0
     tmat_scale = 1.0
     for it in range(scf):
@@ -123,15 +137,22 @@ def run_mini(atoms: int = 4, energies: int = 4, scf: int = 2,
                 # host round-trip), and the solve goes through the
                 # public scipy symbols: with SCILIB_LAPACK=1 these are
                 # the patched solver tier, without it the native path
-                m = jnp.eye(n, dtype=tg.dtype) - z * tg
+                m = kkr_matrix(tg, z)
                 lu_piv = jsl.lu_factor(m)
                 tau = jsl.lu_solve(lu_piv, tm)
                 # verification on the host side (numpy): not BLAS stream
-                resid = float(np.max(np.abs(
-                    np.asarray(m) @ np.asarray(tau) - np.asarray(tm))))
-                max_resid = max(max_resid, resid)
-                energy += float(np.real(np.trace(np.asarray(tau)))) / n
+                mh, th = np.asarray(m), np.asarray(tau)
+                r = mh @ th - np.asarray(tm)
+                max_resid = max(max_resid, float(np.max(np.abs(r))))
+                # LAPACK's xGET02 test ratio, ||M tau - T||_1 /
+                # (||M||_1 ||tau||_1 n eps): O(1) for a backward-stable
+                # solve at this dtype's precision
+                max_ratio = max(max_ratio, float(
+                    np.linalg.norm(r, 1) / (np.linalg.norm(mh, 1)
+                                            * np.linalg.norm(th, 1)
+                                            * n * eps)))
+                energy += float(np.real(np.trace(th))) / n
                 n_solves += 1
         tmat_scale *= 0.98  # SCF mixing proxy
     return {"energy": energy, "max_resid": max_resid,
-            "n_solves": n_solves}
+            "max_ratio": max_ratio, "n_solves": n_solves}

@@ -10,9 +10,8 @@ Public surface:
   (async by default; ``SCILIB_SYNC=1`` or ``runtime.sync()`` to fence).
 * :mod:`repro.core.policy` — Mem-Copy / counter / Device-First-Use /
   pinned / cpu data-movement policies.
-* :mod:`repro.core.memspace` — portable logical HOST/DEVICE memory
-  tiers mapped onto the backend's real memory kinds (simulated-tier
-  fallback on single-kind backends).
+* :mod:`repro.core.memspace` — logical HOST/DEVICE memory tiers mapped
+  onto the backend's real placements (host CPU vs HBM on a TPU).
 * :mod:`repro.core.callsite` — per-call-site fingerprints and profiles
   (the paper's patched call sites; drives ``SCILIB_ADAPTIVE=1``).
 * :mod:`repro.core.residency` — the residency engine: the one byte-

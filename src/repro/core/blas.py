@@ -246,10 +246,31 @@ def _batch_of(*arrays) -> int:
 # ----------------------------------------------------------------------- #
 # jitted arithmetic (shape-cached by jax)                                  #
 # ----------------------------------------------------------------------- #
+#: fp64 contractions longer than this are summed in chunks: a TPU runs
+#: fp64 dot_general as multi-limb passes whose scratch is about 8x the
+#: operands' bytes, so PARSEC's 93536-long contraction against its 1.8 GB
+#: panel would need 15 GB of HBM in one piece (about 5 GB in chunks)
+_WIDE_K_CHUNK = 8192
+
+
+def _matmul(a, b):
+    """``a @ b`` on the kernels' matmul, contraction-chunked for fp64."""
+    from repro.kernels import ops as kops
+    k = a.shape[-1]
+    if a.dtype not in (jnp.float64, jnp.complex128) or k <= _WIDE_K_CHUNK:
+        return kops.matmul(a, b)
+    n = -(-k // _WIDE_K_CHUNK)
+    edges = [k * i // n for i in range(n + 1)]
+    out = None
+    for k0, k1 in zip(edges, edges[1:]):
+        part = kops.matmul(a[..., k0:k1], b[..., k0:k1, :])
+        out = part if out is None else out + part
+    return out
+
+
 @functools.partial(jax.jit, static_argnames=("trans_a", "trans_b", "has_c"))
 def _gemm_kernel(a, b, c, alpha, beta, *, trans_a, trans_b, has_c):
-    from repro.kernels import ops as kops
-    acc = kops.matmul(_op(a, trans_a), _op(b, trans_b))
+    acc = _matmul(_op(a, trans_a), _op(b, trans_b))
     out = alpha.astype(acc.dtype) * acc
     if has_c:
         out = out + beta.astype(acc.dtype) * c

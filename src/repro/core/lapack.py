@@ -16,8 +16,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import blas
+from repro.core import memspace
 from repro.core import runtime as rtm
 
 DEFAULT_NB = 128
@@ -42,43 +44,53 @@ def _note_panel(prec: str, m: int, nb: int, panel: jax.Array) -> None:
         rt.note_panel(prec, m, nb, panel)
 
 
-def _pivot_panel(panel: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Unblocked LU with partial pivoting on a (m x nb) panel.
+def _store(factor: jax.Array, idx, block: jax.Array) -> jax.Array:
+    """``factor.at[idx].set(block)`` with the block first brought to the
+    factor's tier: a panel factored on the host, or an update the runtime
+    kept on the host, must not drag the whole factor off the device."""
+    return factor.at[idx].set(
+        memspace.put(block, memspace.tier_of(factor), check=False))
 
-    jit-compiled; returns the factored panel and local pivot rows.
-    """
 
-    @jax.jit
-    def factor(p):
-        m, nb = p.shape
+@jax.jit
+def _getf2(p: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Unblocked LU with partial pivoting on a (m x nb) panel; returns
+    the factored panel and its local pivot rows."""
+    m, nb = p.shape
 
-        def body(j, carry):
-            p, piv = carry
-            col = p[:, j]
-            mag = jnp.abs(col)
-            mask = jnp.arange(m) < j
-            mag = jnp.where(mask, -jnp.inf, mag)
-            r = jnp.argmax(mag)
-            piv = piv.at[j].set(r.astype(piv.dtype))
-            # swap rows j <-> r
-            rowj, rowr = p[j], p[r]
-            p = p.at[j].set(rowr).at[r].set(rowj)
-            pivval = p[j, j]
-            scale = jnp.where(pivval != 0, 1.0 / pivval, 0.0)
-            below = jnp.arange(m) > j
-            l = jnp.where(below, p[:, j] * scale, p[:, j])
-            p = p.at[:, j].set(l)
-            # rank-1 update of the trailing panel columns
-            trail = jnp.arange(nb) > j
-            lcol = jnp.where(below, l, 0.0)[:, None]
-            urow = jnp.where(trail, p[j], 0.0)[None, :]
-            p = p - lcol * urow
-            return p, piv
+    def body(j, carry):
+        p, piv = carry
+        col = p[:, j]
+        mag = jnp.abs(col)
+        mask = jnp.arange(m) < j
+        mag = jnp.where(mask, -jnp.inf, mag)
+        r = jnp.argmax(mag)
+        piv = piv.at[j].set(r.astype(piv.dtype))
+        # swap rows j <-> r
+        rowj, rowr = p[j], p[r]
+        p = p.at[j].set(rowr).at[r].set(rowj)
+        pivval = p[j, j]
+        scale = jnp.where(pivval != 0, 1.0 / pivval, 0.0)
+        below = jnp.arange(m) > j
+        l = jnp.where(below, p[:, j] * scale, p[:, j])
+        p = p.at[:, j].set(l)
+        # rank-1 update of the trailing panel columns
+        trail = jnp.arange(nb) > j
+        lcol = jnp.where(below, l, 0.0)[:, None]
+        urow = jnp.where(trail, p[j], 0.0)[None, :]
+        p = p - lcol * urow
+        return p, piv
 
-        piv0 = jnp.zeros(nb, dtype=jnp.int32)
-        return jax.lax.fori_loop(0, nb, body, (p, piv0))
+    piv0 = jnp.zeros(nb, dtype=jnp.int32)
+    return jax.lax.fori_loop(0, nb, body, (p, piv0))
 
-    return factor(panel)
+
+def _pivot_panel(panel: jax.Array) -> Tuple[jax.Array, np.ndarray]:
+    """getf2 where LAPACK runs it, on the CPU: the panel is streamed to
+    the HOST tier and factored there.  Returns the factored panel, still
+    host-side, and the local pivot rows as numpy."""
+    fpanel, lpiv = _getf2(memspace.put(panel, memspace.HOST, check=False))
+    return fpanel, np.asarray(lpiv)
 
 
 def getrf(a: jax.Array, nb: int = DEFAULT_NB
@@ -94,35 +106,36 @@ def getrf(a: jax.Array, nb: int = DEFAULT_NB
     prec = _prec(a.dtype)
     k_max = min(m, n)
     lu = a
-    piv = jnp.arange(m, dtype=jnp.int32)
+    piv = np.arange(m, dtype=np.int32)
     for j0 in range(0, k_max, nb):
         jb = min(nb, k_max - j0)
         panel = lu[j0:, j0:j0 + jb]
         fpanel, lpiv = _pivot_panel(panel)
         _note_panel(prec, m - j0, jb, fpanel)
         # apply local pivots to the whole rows (left + right of panel)
-        perm = jnp.arange(m - j0)
+        perm = np.arange(m - j0)
         for jj in range(jb):           # compose swaps (host loop, nb small)
             r = lpiv[jj]
-            perm = perm.at[jj].set(perm[r]).at[r].set(perm[jj])
-        abs_perm = jnp.concatenate([jnp.arange(j0), perm + j0])
+            perm[jj], perm[r] = perm[r], perm[jj]
+        abs_perm = np.concatenate([np.arange(j0), perm + j0])
         lu = lu[abs_perm]
         piv = piv[abs_perm]
-        lu = lu.at[j0:, j0:j0 + jb].set(fpanel)
+        lu = _store(lu, (slice(j0, None), slice(j0, j0 + jb)), fpanel)
         if j0 + jb < n:
             # U12 = L11^{-1} A12           (trsm, unit lower)
             a12 = lu[j0:j0 + jb, j0 + jb:]
             l11 = lu[j0:j0 + jb, j0:j0 + jb]
             u12 = blas.trsm(l11, a12, side="L", uplo="L", trans="N",
                             diag="U")
-            lu = lu.at[j0:j0 + jb, j0 + jb:].set(u12)
+            lu = _store(lu, (slice(j0, j0 + jb), slice(j0 + jb, None)), u12)
             if j0 + jb < m:
                 # A22 -= L21 U12           (gemm: the hot spot)
                 l21 = lu[j0 + jb:, j0:j0 + jb]
                 a22 = lu[j0 + jb:, j0 + jb:]
                 upd = blas.gemm(l21, u12, a22, alpha=-1.0, beta=1.0)
-                lu = lu.at[j0 + jb:, j0 + jb:].set(upd)
-    return lu, piv
+                lu = _store(lu, (slice(j0 + jb, None),
+                                 slice(j0 + jb, None)), upd)
+    return lu, jnp.asarray(piv)
 
 
 def getrs(lu: jax.Array, piv: jax.Array, b: jax.Array) -> jax.Array:
@@ -146,6 +159,12 @@ def gesv(a: jax.Array, b: jax.Array, nb: int = DEFAULT_NB) -> jax.Array:
     return getrs(lu, piv, b)
 
 
+@jax.jit
+def _chol_block(blk: jax.Array) -> jax.Array:
+    # jnp.linalg.cholesky symmetrizes its input, so feed full blocks
+    return jnp.linalg.cholesky(blk)
+
+
 def potrf(a: jax.Array, nb: int = DEFAULT_NB, *,
           uplo: str = "L") -> jax.Array:
     """Blocked Cholesky (syrk-shaped gemm + trsm + small unblocked factor).
@@ -159,12 +178,7 @@ def potrf(a: jax.Array, nb: int = DEFAULT_NB, *,
         l = potrf(jnp.conj(a.T), nb, uplo="L")
         return jnp.conj(l.T)
     n = a.shape[0]
-    l = jnp.zeros_like(a)
-
-    @jax.jit
-    def chol_block(blk):
-        # jnp.linalg.cholesky symmetrizes its input, so feed full blocks
-        return jnp.linalg.cholesky(blk)
+    l = jnp.zeros(a.shape, a.dtype)
 
     for j0 in range(0, n, nb):
         jb = min(nb, n - j0)
@@ -174,8 +188,8 @@ def potrf(a: jax.Array, nb: int = DEFAULT_NB, *,
         if j0 > 0:
             a11 = blas.gemm(l10, l10, a11, alpha=-1.0, beta=1.0,
                             trans_b="C")
-        l11 = chol_block(a11)
-        l = l.at[j0:j0 + jb, j0:j0 + jb].set(l11)
+        l11 = _chol_block(a11)
+        l = _store(l, (slice(j0, j0 + jb), slice(j0, j0 + jb)), l11)
         if j0 + jb < n:
             l20 = l[j0 + jb:, :j0]
             a21 = a[j0 + jb:, j0:j0 + jb]
@@ -185,7 +199,7 @@ def potrf(a: jax.Array, nb: int = DEFAULT_NB, *,
             # L21 = A21 L11^{-H}    (right-side trsm)
             l21 = blas.trsm(l11, a21, side="R", uplo="L", trans="C",
                             diag="N")
-            l = l.at[j0 + jb:, j0:j0 + jb].set(l21)
+            l = _store(l, (slice(j0 + jb, None), slice(j0, j0 + jb)), l21)
     return l
 
 

@@ -1,33 +1,36 @@
-"""Portable logical memory tiers over JAX memory kinds.
+"""Logical memory tiers over the backend's real placements.
 
 The paper's runtime moves buffers between two physical homes: host DRAM
 and device HBM, coherently addressable from both sides (GH200 NVLink-C2C).
-JAX exposes that split as *memory kinds* on a sharding — but the set of
-kinds is backend-dependent: a TPU/GPU backend offers ``device`` +
-``pinned_host`` (+ ``unpinned_host``), while the CPU backend of a dev
-container offers exactly one kind.  Hard-coding kind strings therefore
-breaks every policy on CPU before a single byte moves.
-
-This module maps two *logical* tiers onto whatever the backend has:
+This module maps two *logical* tiers onto two real JAX placements:
 
 * :data:`HOST`   — where CPU-first-touched data lives (the malloc side),
 * :data:`DEVICE` — where offloaded BLAS wants its operands (the HBM side).
 
-``probe()`` inspects ``addressable_memories()`` once.  When the backend
-has distinct kinds, ``put``/``tier_of`` are thin wrappers over real
-``device_put`` transfers.  When it has only one kind (CPU container),
-the mem-space runs in **simulated-tier** mode: the tier tag is carried in
-a side table keyed on buffer identity, a cross-tier ``put`` materializes
-a physical copy (so first-touch movement has a real cost and a distinct
-destination buffer), and every policy runs identically to the multi-kind
-backends — movement is still counted in the runtime statistics.
+``probe()`` resolves the pair once per backend:
+
+* **Accelerator backend (TPU).**  HOST is the CPU backend's device and
+  DEVICE is the accelerator's own memory.  Code computing on a HOST
+  array runs on the host CPU, exactly like the paper's CPU code touching
+  malloc'd pages.  (The TPU's ``pinned_host`` kind cannot be an operand
+  of ordinary device ops, so it cannot back a tier user code computes
+  on.)  DEVICE placements, and the gathered results of sharded calls,
+  are *uncommitted* arrays on the default device, so user code that
+  combines one with a HOST array computes on the host, as a CPU thread
+  reading a device-resident page would.
+* **CPU backend.**  There is one device; HOST is its ``unpinned_host``
+  memory kind and DEVICE its ``device`` kind.  Both are ordinary
+  operands to every op, and a ``put`` between them is a real transfer.
+
+A backend that cannot express both placements is an error: there is no
+simulated fallback.
 
 The DEVICE tier additionally carries a **device index**: a node with N
 local accelerators has N device tiers, one per HBM.  ``probe()``
-enumerates them from ``len(jax.devices())``, and ``SCILIB_DEVICES=n``
-forces a simulated N-tier layout (mirroring the single-kind fallback) so
-the multi-device tile scheduler can be exercised on any backend,
-including this CPU container.  ``put_block`` re-homes a buffer to one
+enumerates them from ``len(jax.devices())``; ``SCILIB_DEVICES=n`` may
+ask for more tiers than there are devices, and then the extra tiers are
+logical (a copy tagged with its index) so the multi-device tile
+scheduler can run on one device.  ``put_block`` re-homes a buffer to one
 specific device tier; ``device_of`` reads the index back.
 """
 from __future__ import annotations
@@ -39,6 +42,8 @@ from contextvars import ContextVar
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
+import numpy as np
+from jax.sharding import Sharding, SingleDeviceSharding
 
 from repro.core import faults as flt
 
@@ -107,16 +112,46 @@ def _wrap_move_error(exc: BaseException, *, device: Optional[int],
 
 @dataclasses.dataclass(frozen=True)
 class MemSpace:
-    """Resolved mapping of logical tiers onto one backend's memory kinds."""
+    """Resolved mapping of the logical tiers onto one backend."""
 
-    host_kind: str      # physical kind backing the HOST tier
-    device_kind: str    # physical kind backing the DEVICE tier
-    simulated: bool     # True when the backend exposes a single kind
+    host: Sharding      # placement backing the HOST tier
+    device: Sharding    # placement backing the (whole-call) DEVICE tier
     backend: str        # jax.default_backend() at probe time
     n_devices: int = 1  # number of logical DEVICE tiers (accelerators)
 
-    def kind_of(self, tier: str) -> str:
-        return self.host_kind if tier == HOST else self.device_kind
+    @property
+    def host_kind(self) -> str:
+        return self.host.memory_kind
+
+    @property
+    def device_kind(self) -> str:
+        return self.device.memory_kind
+
+    @property
+    def host_platform(self) -> str:
+        return _device(self.host).platform
+
+    @property
+    def device_platform(self) -> str:
+        return _device(self.device).platform
+
+    def sharding(self, tier: str) -> Sharding:
+        return self.host if tier == HOST else self.device
+
+    def is_host(self, sharding: Sharding) -> bool:
+        """Does an array with this sharding live on the HOST tier?"""
+        device = _device(sharding)
+        kind = sharding.memory_kind or device.default_memory().kind
+        return device == _device(self.host) and kind == self.host_kind
+
+    def describe(self) -> str:
+        return (f"host={self.host_platform}:{self.host_kind} "
+                f"device={self.device_platform}:{self.device_kind} "
+                f"backend={self.backend} n_devices={self.n_devices}")
+
+
+def _device(sharding: Sharding) -> jax.Device:
+    return next(iter(sharding.device_set))
 
 
 def device_bytes_from_env() -> Optional[int]:
@@ -137,6 +172,10 @@ def probe(device: Optional[jax.Device] = None,
     any runtime), it comes from the env-derived config — the single
     ``SCILIB_*`` ingestion boundary — falling back to
     ``len(jax.devices())``.
+
+    Raises ``RuntimeError`` when the backend cannot hold the two tiers
+    apart: an accelerator without a CPU backend beside it, or a CPU
+    device without an ``unpinned_host`` memory kind.
     """
     d = device if device is not None else jax.devices()[0]
     backend = jax.default_backend()
@@ -144,36 +183,29 @@ def probe(device: Optional[jax.Device] = None,
         from repro.core.config import OffloadConfig
         n_devices = OffloadConfig.from_env().devices
     if n_devices is None:
-        try:
-            n_devices = len(jax.devices())
-        except Exception:  # pragma: no cover - no devices
-            n_devices = 1
-    try:
+        n_devices = len(jax.devices())
+    if d.platform == "cpu":
         kinds = [m.kind for m in d.addressable_memories()]
-    except Exception:  # pragma: no cover - very old jaxlib
-        kinds = []
-    try:
-        device_kind = d.default_memory().kind
-    except Exception:  # pragma: no cover
-        device_kind = kinds[0] if kinds else "device"
-    if device_kind not in kinds and kinds:
-        device_kind = kinds[0]
-    # prefer pinned host memory for the HOST tier (DMA-able, what the
-    # paper's cudaMallocHost-style staging uses), else any non-device kind
-    host_kind = next((k for k in ("pinned_host", "unpinned_host")
-                      if k in kinds and k != device_kind), None)
-    if host_kind is None:
-        host_kind = next((k for k in kinds if k != device_kind), None)
-    if host_kind is None:
-        return MemSpace(host_kind=device_kind, device_kind=device_kind,
-                        simulated=True, backend=backend,
-                        n_devices=n_devices)
-    return MemSpace(host_kind=host_kind, device_kind=device_kind,
-                    simulated=False, backend=backend, n_devices=n_devices)
+        if "unpinned_host" not in kinds:
+            raise RuntimeError(
+                f"CPU device {d} has memory kinds {kinds}; the HOST tier "
+                f"needs 'unpinned_host'")
+        host = SingleDeviceSharding(d, memory_kind="unpinned_host")
+    else:
+        try:
+            cpu = jax.devices("cpu")[0]
+        except RuntimeError as exc:
+            raise RuntimeError(
+                f"{d.platform} backend has no CPU backend beside it for "
+                f"the HOST tier ({exc})") from exc
+        host = SingleDeviceSharding(cpu, memory_kind=cpu.default_memory().kind)
+    dev = SingleDeviceSharding(d, memory_kind=d.default_memory().kind)
+    return MemSpace(host=host, device=dev, backend=backend,
+                    n_devices=n_devices)
 
 
 # --------------------------------------------------------------------- #
-# module state: active mapping + simulated-tier tag table                 #
+# module state: active mapping + logical device-tier tags               #
 # --------------------------------------------------------------------- #
 # The *installed* mapping is context-local (a session's devices layout
 # must not leak into a neighbouring thread); the lazily-probed fallback
@@ -183,12 +215,11 @@ _ACTIVE: ContextVar[Optional[MemSpace]] = (
 _PROBED: Optional[MemSpace] = None
 _PROBE_LOCK = threading.Lock()
 
-# id(arr) -> (weakref(arr), logical tier, device index); only consulted
-# in simulated mode, but tags are maintained unconditionally so a mapping
-# re-probe (e.g. tests switching modes) never orphans tier state.  The
-# table is process-wide (a tier is a property of the buffer, not of the
+# id(arr) -> (weakref(arr), logical device index): the copies put_block
+# makes for logical device tiers that share one real device.  The table
+# is process-wide (a tier is a property of the buffer, not of the
 # observing session) and its dict operations are GIL-atomic.
-_TIERS: Dict[int, Tuple[weakref.ref, str, int]] = {}
+_LOGICAL: Dict[int, Tuple[weakref.ref, int]] = {}
 
 
 def active() -> MemSpace:
@@ -219,7 +250,7 @@ def reset() -> None:
     _ACTIVE.set(None)
     with _PROBE_LOCK:
         _PROBED = None
-    _TIERS.clear()
+    _LOGICAL.clear()
 
 
 def n_devices() -> int:
@@ -227,51 +258,51 @@ def n_devices() -> int:
     return active().n_devices
 
 
-def _tag(x: jax.Array, tier: str, device: int = 0) -> None:
+def _tag(x: jax.Array, device: int) -> None:
     key = id(x)
 
     def _drop(_ref, key=key):
-        _TIERS.pop(key, None)
+        _LOGICAL.pop(key, None)
 
-    _TIERS[key] = (weakref.ref(x, _drop), tier, device)
+    _LOGICAL[key] = (weakref.ref(x, _drop), device)
+
+
+def _logical_device(x) -> Optional[int]:
+    ent = _LOGICAL.get(id(x))
+    if ent is not None and ent[0]() is not None:
+        return ent[1]
+    return None
 
 
 def tier_of(x) -> str:
-    """Logical tier of a buffer (HOST or DEVICE).
-
-    Untagged buffers default to DEVICE: on accelerator backends freshly
-    created arrays are born in device memory, and the simulated mode
-    mirrors that so policies behave identically everywhere.  Data that is
-    semantically CPU-first-touched must come through :func:`host_array` /
-    ``put(x, HOST)``, exactly like the paper's malloc'd inputs.
-    """
-    ent = _TIERS.get(id(x))
-    if ent is not None and ent[0]() is not None:
-        return ent[1]
-    ms = active()
-    if ms.simulated:
+    """Logical tier of a buffer (HOST or DEVICE), read from where the
+    buffer physically is.  Anything that is not on the HOST placement —
+    including fresh arrays born on the default device and non-array
+    leaves — is DEVICE.  Data that is semantically CPU-first-touched
+    comes in through :func:`host_array` / ``put(x, HOST)``, exactly like
+    the paper's malloc'd inputs."""
+    if _logical_device(x) is not None:
         return DEVICE
     try:
-        kind = x.sharding.memory_kind or ms.device_kind
-    except (AttributeError, TypeError) as exc:  # non-array leaves
+        sharding = x.sharding
+    except AttributeError as exc:  # non-array leaves
         _debug_log(f"tier_of: no sharding on {type(x).__name__} "
                    f"({exc!r}); assuming DEVICE", level=2)
         return DEVICE
-    return HOST if kind == ms.host_kind else DEVICE
+    return HOST if active().is_host(sharding) else DEVICE
 
 
 def device_of(x) -> Optional[int]:
     """Device-tier index of a buffer, or None when it has no explicit
     device placement (host-resident or never routed by the scheduler)."""
-    ent = _TIERS.get(id(x))
-    if ent is not None and ent[0]() is not None:
-        return ent[2] if ent[1] == DEVICE else None
-    ms = active()
-    if ms.simulated:
+    logical = _logical_device(x)
+    if logical is not None:
+        return logical
+    if tier_of(x) != DEVICE:
         return None
     try:
         devs = list(x.devices())
-    except (AttributeError, TypeError) as exc:  # non-array / old jaxlib
+    except AttributeError as exc:  # non-array leaves
         _debug_log(f"device_of: no devices() on {type(x).__name__} "
                    f"({exc!r})", level=2)
         return None
@@ -279,109 +310,100 @@ def device_of(x) -> Optional[int]:
         return None
     try:
         return jax.devices().index(devs[0])
-    except ValueError:  # pragma: no cover - device of another backend
+    except ValueError:
         return None
 
 
+def _move(x: jax.Array, tier: str) -> jax.Array:
+    """The physical transfer behind :func:`put`.  On an accelerator a
+    DEVICE placement is left *uncommitted* on the default device (see the
+    module docstring); everything else is a plain ``device_put``."""
+    ms = active()
+    if tier == DEVICE and ms.device_platform != "cpu":
+        return jax.device_put(np.asarray(x))
+    return jax.device_put(x, ms.sharding(tier))
+
+
 def put(x: jax.Array, tier: str, *, check: bool = True) -> jax.Array:
-    """Re-home a buffer to a logical tier (the ``move_pages()`` analogue).
+    """Re-home a buffer to a logical tier (the ``move_pages()`` analogue):
+    a real transfer to the tier's placement, or ``x`` itself when it is
+    already there.
 
-    Real-tier mode issues a physical ``device_put`` to the mapped memory
-    kind.  Simulated mode materializes a copy tagged with the target tier
-    — the source keeps its own tag, so Mem-Copy-style round trips remain
-    observable and DFU's placement registry gets a distinct device-side
-    buffer to cache.
-
-    A real movement toward DEVICE is a transfer guard site: the fault
-    hook runs first (injection point — before any state changes), and a
+    A movement toward DEVICE is a transfer guard site: the fault hook
+    runs first (injection point — before any state changes), and a
     failure of the movement itself raises a typed
     :class:`repro.core.faults.TransferError` / ``DeviceOOMError`` the
     runtime's retry/fallback guard can absorb.  ``check=False`` opts a
     call site out of injection (host-path streaming, explicit pins).
     """
-    ms = active()
-    if not ms.simulated:
-        kind = ms.kind_of(tier)
-        cur = x.sharding.memory_kind or ms.device_kind
-        if cur == kind:
-            return x
-        hook = _FAULT_HOOK.get()
-        if check and tier == DEVICE and hook is not None:
-            hook(None, x.nbytes)
-        try:
-            return jax.device_put(x, x.sharding.with_memory_kind(kind))
-        except _MOVE_ERRORS as exc:
-            raise _wrap_move_error(exc, device=None,
-                                   nbytes=x.nbytes) from exc
     if tier_of(x) == tier:
         return x
     hook = _FAULT_HOOK.get()
     if check and tier == DEVICE and hook is not None:
         hook(None, x.nbytes)
-    import jax.numpy as jnp
     try:
-        moved = jnp.array(x, copy=True)
+        return _move(x, tier)
     except _MOVE_ERRORS as exc:
         raise _wrap_move_error(exc, device=None, nbytes=x.nbytes) from exc
-    _tag(moved, tier)
-    return moved
 
 
 def put_block(x: jax.Array, device: int) -> jax.Array:
     """Re-home a buffer onto one specific DEVICE tier (tile scheduling).
 
-    With multiple *real* devices the block is ``device_put`` onto that
-    accelerator's memory.  Otherwise the device tier is logical: a copy
-    tagged ``(DEVICE, device)`` — same first-touch cost model as
-    :func:`put`, so per-device movement statistics stay honest on the
-    CPU container's ``SCILIB_DEVICES=n`` layout.
+    With several real devices the block is ``device_put`` onto that
+    accelerator (tier ``i`` on device ``i % len(jax.devices())``).  When
+    the tiers outnumber the real devices the copy is also tagged with its
+    tier index, so tiers sharing a device stay apart — the
+    same first-touch cost model as :func:`put`, so per-device movement
+    statistics stay honest on a one-device ``SCILIB_DEVICES=n`` layout.
 
-    Like :func:`put`, a real movement is a transfer guard site — the
-    fault hook fires first (with the device index, so ``device=``
-    rules in ``SCILIB_FAULTS`` target one tier), and movement failures
-    raise the typed hierarchy.
+    Like :func:`put`, a movement is a transfer guard site — the fault
+    hook fires first (with the device index, so ``device=`` rules in
+    ``SCILIB_FAULTS`` target one tier), and movement failures raise the
+    typed hierarchy.
     """
-    if tier_of(x) == DEVICE and device_of(x) == device:
+    real = jax.devices()
+    ms = active()
+    logical = ms.n_devices > len(real)
+    if (_logical_device(x) if logical else device_of(x)) == device:
         return x
     hook = _FAULT_HOOK.get()
     if hook is not None:
         hook(device, x.nbytes)
+    target = real[device % len(real)] if len(real) > 1 else ms.device
     try:
-        real = jax.devices()
-    except RuntimeError as exc:  # pragma: no cover - no devices
-        _debug_log(f"put_block: jax.devices() unavailable ({exc!r})")
-        real = []
-    if len(real) > 1:
-        try:
-            moved = jax.device_put(x, real[device % len(real)])
-        except _MOVE_ERRORS as exc:
-            raise _wrap_move_error(exc, device=device,
-                                   nbytes=x.nbytes) from exc
-        _tag(moved, DEVICE, device)
-        return moved
-    import jax.numpy as jnp
-    try:
-        moved = jnp.array(x, copy=True)
+        moved = jax.device_put(x, target, may_alias=False)
     except _MOVE_ERRORS as exc:
         raise _wrap_move_error(exc, device=device,
                                nbytes=x.nbytes) from exc
-    _tag(moved, DEVICE, device)
+    if logical:
+        _tag(moved, device)
     return moved
 
 
-def tag_device(x: jax.Array) -> jax.Array:
-    """Mark an array device-resident without moving it (outputs of
-    offloaded compute are born on the device tier)."""
-    ms = active()
-    if ms.simulated and tier_of(x) != DEVICE:
-        _tag(x, DEVICE)
-    return x
+def uncommitted(x: jax.Array) -> jax.Array:
+    """``x`` released from its device, its buffer left where it is.
+
+    The tiles of a sharded call are committed to the devices they ran on,
+    and so is their gathered result; an op combining a committed device
+    array with a HOST array (committed to the CPU) is refused.  Handed
+    back uncommitted, the result behaves like a whole-call DEVICE
+    placement: user code mixing it with host data computes on the host.
+    JAX has no public call that drops the commitment without a copy, so
+    this re-wraps the same device buffers."""
+    if not x.committed:
+        return x
+    from jax._src import array as jax_array
+    return jax_array.ArrayImpl(x.aval, x.sharding, x._arrays,
+                               committed=False)
 
 
-def tag_host(x: jax.Array) -> jax.Array:
-    """Mark an array host-resident without moving it (eviction bookkeeping
-    in simulated mode: the buffer's next device use must re-migrate)."""
-    ms = active()
-    if ms.simulated and tier_of(x) != HOST:
-        _tag(x, HOST)
-    return x
+def gather_home(x: jax.Array) -> jax.Array:
+    """``x`` on the device of the whole-call DEVICE placement: tiles a
+    sharded call computed on several real devices meet there before
+    they are assembled (one op never spans devices).  A device-to-device
+    copy over the interconnect, not host-link traffic."""
+    home = _device(active().device)
+    if x.devices() == {home}:
+        return x
+    return jax.device_put(x, home)

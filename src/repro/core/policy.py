@@ -1,11 +1,10 @@
-"""Data-movement policies (paper §3.2) over portable logical memory tiers.
+"""Data-movement policies (paper §3.2) over logical memory tiers.
 
 The JAX adaptation of the paper's three strategies plus two controls.
-Tiers are the *logical* HOST/DEVICE pair of :mod:`repro.core.memspace`:
-on a TPU/GPU backend they map to real distinct memory kinds (host DRAM
-vs HBM) and every ``put`` is a physical transfer; on a single-kind CPU
-backend the mem-space simulates the tier split (tag + copy) so the same
-policies run — and produce the same statistics — on every backend.
+Tiers are the *logical* HOST/DEVICE pair of :mod:`repro.core.memspace`,
+resolved to real placements per backend (the CPU device vs the
+accelerator on a TPU; the ``unpinned_host`` vs ``device`` memory kinds
+on the CPU backend), so every ``put`` is a physical transfer.
 
 Buffer identity follows the source array object (the JAX analogue of the
 paper's virtual-address identity): placement is cached per buffer, so a
@@ -24,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import numpy as np
 
 from repro.core import memspace
 
@@ -48,10 +48,12 @@ def host_array(x) -> jax.Array:
     """The malloc() analogue: materialize an array on the HOST tier.
 
     Application inputs in the paper are CPU-first-touched; use this for
-    inputs so the offload policies have real movement to manage."""
-    import jax.numpy as jnp
-    x = jnp.asarray(x)
-    return _put(x, HOST_KIND)
+    inputs so the offload policies have real movement to manage.  Host
+    data (numpy, lists) goes straight to the HOST placement, never
+    through the device."""
+    if isinstance(x, jax.Array):
+        return _put(x, HOST_KIND)
+    return jax.device_put(np.asarray(x), memspace.active().host)
 
 
 @dataclasses.dataclass
@@ -94,7 +96,7 @@ class PolicyBase:
         if self.copy_back:
             nbytes = y.nbytes
             return Placement(_put(y, HOST_KIND), moved_bytes=nbytes)
-        return Placement(memspace.tag_device(y))
+        return Placement(y)
 
     def select_device(self, runtime, blocks) -> int:
         """Which device tier runs one tile of a sharded call (BLASX-style
@@ -168,7 +170,6 @@ class DeviceFirstUsePolicy(PolicyBase):
         return Placement(moved, moved_bytes=x.nbytes)
 
     def place_output(self, runtime, y):
-        memspace.tag_device(y)
         runtime.placements.put(id(y), y, y.nbytes, anchor=y)
         return Placement(y)
 

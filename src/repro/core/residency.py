@@ -18,7 +18,9 @@ share.  It is a keyed table of resident entries with
   ``resident_bytes`` exact at all times,
 * **weakref lifecycle** — an entry may be anchored to a live object
   (the application's array); when the anchor dies the entry drops
-  itself, exactly like the old registries' weakref callbacks,
+  itself, exactly like the old registries' weakref callbacks.  An entry
+  whose payload *is* its anchor holds it weakly, so registering a
+  buffer never extends its life,
 * **pin flags** — pinned entries survive arbitrary cap pressure
   (``runtime.pin(x)``, or ``SCILIB_PIN=never-evict`` to pin every
   placement),
@@ -70,6 +72,13 @@ class Entry:
     pinned: bool = False
     uses: int = 0              # lookup hits (LFU / refetch-cost input)
     ref: Optional[weakref.ref] = None   # lifecycle anchor (may be None)
+
+    def value(self):
+        """The payload.  An entry whose payload is its own anchor (a
+        buffer already on the tier it is registered for, such as a
+        device-born output) holds it only through ``ref``: the registry
+        records residency, it never keeps a buffer alive."""
+        return self.ref() if self.payload is None else self.payload
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,6 +289,7 @@ class ResidencyStore:
             if ent.ref is not None and ent.ref() is None:
                 self.drop(key)
                 return None
+            payload = ent.value()
             ent.uses += 1
             self._entries.move_to_end(key)
             self.hits += 1
@@ -289,7 +299,7 @@ class ResidencyStore:
             # place/evict/refetch are rare and always emitted.
             if self.emit is not None and self.cap is not None:
                 self.emit("hit", self.name, ent.nbytes)
-            return ent.payload
+            return payload
 
     def put(self, key: Hashable, payload, nbytes: int, *,
             anchor=None, pinned: bool = False) -> Entry:
@@ -308,6 +318,8 @@ class ResidencyStore:
                 def _lifecycle(_ref, key=key, self=self):
                     self.drop(key)
                 ref = weakref.ref(anchor, _lifecycle)
+            if payload is anchor:
+                payload = None        # held through ``ref`` alone
             ent = Entry(key=key, payload=payload, nbytes=int(nbytes),
                         pinned=pinned or self.pin_new, ref=ref)
             self._entries[key] = ent
@@ -387,7 +399,7 @@ class ResidencyStore:
             if self.emit is not None:
                 self.emit("evict", self.name, ent.nbytes)
             if self.on_evict is not None:
-                self.on_evict(key, ent.payload, ent.nbytes)
+                self.on_evict(key, ent.value(), ent.nbytes)
             if self.pool is not None:
                 self.pool.evicted(self.owner, ent.nbytes)
             return ent

@@ -105,6 +105,11 @@ _PENDING_WINDOW = 32
 #: (long-lived servers over ragged shapes must not leak decisions).
 _DECISION_CACHE_LIMIT = 65536
 
+#: dtypes a device platform's compiler refuses for BLAS-3 arithmetic:
+#: on a TPU complex128 dot_general and triangular_solve abort the
+#: compiler, so such calls stay on the host path
+_HOST_ONLY_DTYPES = {"tpu": frozenset({"complex128"})}
+
 
 # --------------------------------------------------------------------- #
 # tile plans (built by core.blas, executed by the scheduler below)       #
@@ -194,6 +199,9 @@ class CallContext:
     split_check: Optional[Callable[..., jax.Array]] = None
     site: Optional[cs.CallSiteProfile] = None
     site_id: str = ""
+    # the offload path's placed operands (set by _offload_whole), which
+    # the split residual check and an escalated rerun reuse
+    placed: Optional[list] = None
 
 
 @dataclasses.dataclass
@@ -205,6 +213,7 @@ class DispatchDecision:
     n_avg: float = 0.0
     why: str = "threshold"      # "cache" | "threshold" | "adaptive:probe"
     #                           # | "adaptive:locked" | "policy:host-only"
+    #                           # | "dtype:host-only"
     #                           # (+ "+kernel" suffix on the pallas venue)
     plan: Optional[TilePlan] = None
     timed: bool = False         # adaptive probe: block + bill path timing
@@ -306,6 +315,9 @@ class RuntimeStats:
     callsites: Optional[cs.CallSiteRegistry] = None
     # the owning runtime's per-device circuit breaker (health section)
     breaker: Optional[flt.HealthTracker] = None
+    # the platforms each path's results were computed on ("host" /
+    # "offload" -> {"cpu", "tpu", ...}), read off the output buffers
+    platforms: Dict[str, set] = dataclasses.field(default_factory=dict)
 
     def routine(self, name: str) -> RoutineStats:
         return self.per_routine.setdefault(name, RoutineStats())
@@ -345,6 +357,10 @@ class RuntimeStats:
                          f"{r.on_host:>7}{r.seconds:>10.3f}{gb:>10.3f}"
                          f"{reuse:>8.1f}{dhit:>7.2f}")
         lines.append(f"uninstrumented calls: {self.uninstrumented_calls}")
+        if self.platforms:
+            lines.append("platforms: " + " ".join(
+                f"{path}={','.join(sorted(p))}"
+                for path, p in sorted(self.platforms.items())))
         if self.evictions:
             lines.append(f"evictions: {self.evictions} "
                          f"({self.evicted_bytes / 1e9:.3f} GB)")
@@ -731,13 +747,11 @@ class OffloadRuntime:
                                     session=self.session_id)
 
     def _on_placement_evict(self, key, placed, nbytes: int) -> None:
-        """Cap pressure pushed a placement out: re-tag the buffer
-        host-side so the next use re-migrates (and is counted again).
-        JAX arrays are immutable: on real-tier backends the HBM itself
-        is released once the application's own references die — the
-        registry cannot forcibly move a borrowed handle — while the
-        simulated tier models the re-migration cost with a real copy."""
-        memspace.tag_host(placed)
+        """Cap pressure pushed a placement out: the registry forgets it, so
+        the source's next use re-migrates (and is counted again).  JAX
+        arrays are immutable: the HBM itself is released once the
+        application's own references die — the registry cannot forcibly
+        move a borrowed handle."""
         with self._stats_lock:
             self.stats.evictions += 1
             self.stats.evicted_bytes += nbytes
@@ -748,7 +762,6 @@ class OffloadRuntime:
     def _block_evict_hook(self, device: int):
         """Per-device eviction callback for the tile-block stores."""
         def _on_evict(key, placed, nbytes, device=device, self=self):
-            memspace.tag_host(placed)
             with self._stats_lock:
                 dst = self.stats.device(device)
                 dst.evictions += 1
@@ -875,7 +888,7 @@ class OffloadRuntime:
         self._emit_event("fallback", f"{exc.kind}@dev{dev}", exc.nbytes)
         if self.debug >= 1:
             print(f"[scilib] {call.routine} falling back to host: {exc}")
-        return call.compute(*self._harmonize(call.arrays, st))
+        return self._run_host(call, st)
 
     def pin(self, x: jax.Array) -> jax.Array:
         """Pin a buffer on the device tier: place it now if needed and
@@ -1070,7 +1083,10 @@ class OffloadRuntime:
                 "kernel", lambda t=tile, p=placed: t.compute(*p),
                 device=dev, nbytes=0, st=st))
             dst.tiles += 1
-        out = plan.gather(outs)
+        # tiles computed on several real devices meet on the whole-call
+        # device before the gather (one op never spans devices)
+        out = memspace.uncommitted(
+            plan.gather([memspace.gather_home(o) for o in outs]))
         if self.policy.persistent:
             for tile, dev, block in zip(plan.tiles, devices, outs):
                 self.block_stores[dev].put(
@@ -1079,8 +1095,6 @@ class OffloadRuntime:
         if self.policy.copy_back:
             st.bytes_out += out.nbytes
             out = memspace.put(out, memspace.HOST)
-        else:
-            memspace.tag_device(out)
         st.offloaded += 1
         st.sharded += 1
         st.tiles += len(plan.tiles)
@@ -1261,6 +1275,10 @@ class OffloadRuntime:
         if decision.offload and not self.policy.offloads:
             decision.offload = False
             decision.why = "policy:host-only"
+        if decision.offload and call.arrays[0].dtype.name in (
+                _HOST_ONLY_DTYPES.get(self.memspace.device_platform, ())):
+            decision.offload = False
+            decision.why = "dtype:host-only"
         if decision.offload and not self.health.any_usable():
             # degraded mode: every device tier quarantined — keep
             # serving on the host path until a half-open probe readmits
@@ -1440,9 +1458,8 @@ class OffloadRuntime:
     def _execute(self, call: CallContext, decision: DispatchDecision,
                  st: RoutineStats) -> Tuple[jax.Array, Tuple[int, ...]]:
         if not decision.offload:
-            out = call.compute(*self._harmonize(call.arrays, st))
             st.on_host += 1
-            return out, ()
+            return self._run_host(call, st), ()
         try:
             if decision.plan is not None:
                 return self._sharded_call(st, decision.plan,
@@ -1489,7 +1506,7 @@ class OffloadRuntime:
             placed.append(p.array)
         # harmonize outside the kernel guard: a retried kernel must not
         # re-bill transient streaming bytes
-        args = self._harmonize(placed, st)
+        args = call.placed = self._harmonize(placed, st)
         # venue selection: the pallas-venue arithmetic replaces the
         # generic jitted compute inside the *same* guarded kernel unit,
         # so injection, retries and breaker trips cover it identically.
@@ -1528,7 +1545,8 @@ class OffloadRuntime:
         result stands."""
         if call.split_check is None:
             return out
-        rel = float(call.split_check(out, *call.arrays))
+        placed = call.placed or self._harmonize(call.arrays)
+        rel = float(call.split_check(out, *placed))
         if rel <= self.precision_rtol:
             return out
         st.escalations += 1
@@ -1542,7 +1560,7 @@ class OffloadRuntime:
             print(f"[scilib] {call.routine} {decision.precision} "
                   f"residual {rel:.2e} > rtol {self.precision_rtol:.2e}"
                   f" -> native fp64")
-        return call.compute(*self._harmonize(call.arrays, st))
+        return call.compute(*(call.placed or self._harmonize(call.arrays)))
 
     # ------------------------------------------------------------------ #
     # stage 5 — record: statistics, site profile, trace                   #
@@ -1551,6 +1569,9 @@ class OffloadRuntime:
                 out: jax.Array, devices: Tuple[int, ...], dt: float,
                 st: RoutineStats) -> None:
         st.seconds += dt
+        path = "offload" if decision.offload else "host"
+        plats = self.stats.platforms.setdefault(path, set())
+        plats.update(d.platform for d in out.devices())
         if decision.offload and decision.venue == "pallas":
             st.kernel_calls += 1
             st.kernel_seconds += dt
@@ -1588,21 +1609,26 @@ class OffloadRuntime:
                   f"({decision.why})")
 
     # ------------------------------------------------------------------ #
-    def _harmonize(self, arrays, st) -> list:
-        """Execution-space harmonization: XLA cannot mix memory spaces in
-        one op, so operands a policy left host-resident are streamed in
-        transiently (GH200's coherent remote read, made explicit). The
-        placement registry is untouched — residency stays host."""
-        simulated = self.memspace.simulated
+    def _run_host(self, call: CallContext, st: RoutineStats) -> jax.Array:
+        """The host path: every operand on the HOST tier, the arithmetic
+        on the host CPU, the result left there (the paper's CPU BLAS)."""
+        out = call.compute(*self._harmonize(call.arrays, st, memspace.HOST))
+        return memspace.put(out, memspace.HOST, check=False)
+
+    def _harmonize(self, arrays, st: Optional[RoutineStats] = None,
+                   tier: str = memspace.DEVICE) -> list:
+        """Execution-space harmonization: one op never mixes placements,
+        so operands outside ``tier`` are streamed in transiently (GH200's
+        coherent remote read, made explicit) and billed to ``st`` when
+        given.  The placement registry is untouched.  Transient
+        streaming is not a placement decision (and the host paths run
+        through here): it never injects faults."""
         out = []
         for a in arrays:
-            if memspace.tier_of(a) != memspace.DEVICE:
-                st.transient_bytes += a.nbytes
-                if not simulated:
-                    # transient streaming, not a placement decision (and
-                    # the host fallback path itself runs through here):
-                    # never inject faults on it
-                    a = memspace.put(a, memspace.DEVICE, check=False)
+            if memspace.tier_of(a) != tier:
+                if st is not None:
+                    st.transient_bytes += a.nbytes
+                a = memspace.put(a, tier, check=False)
             out.append(a)
         return out
 

@@ -19,9 +19,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from repro.kernels import compat
-from repro.kernels.compat import pl, pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -111,7 +110,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lenvec, qg, k, v)

@@ -18,9 +18,16 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-from repro.kernels.compat import pl, pltpu
+
+def fp32_precision(dtype):
+    """Contraction precision for a BLAS product of ``dtype`` operands: an
+    f32 product at full f32 (the MXU's multi-pass mode; its default is one
+    bf16 pass, which rounds every input to 8 bits), anything else at the
+    operands' own precision."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -29,7 +36,8 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=acc_ref.dtype)
+                            preferred_element_type=acc_ref.dtype,
+                            precision=fp32_precision(a_ref.dtype))
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _store():
@@ -74,7 +82,7 @@ def gemm(a: jax.Array, b: jax.Array, *, bm: int = 256, bk: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ap, bp)

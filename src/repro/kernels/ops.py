@@ -3,11 +3,17 @@
 ``matmul``/``trsm``/``attention`` are what the BLAS surface and the model
 stack call. Backend selection:
 
-* TPU backend -> Pallas kernels (compiled), except dtypes the MXU lacks.
-* CPU backend -> XLA reference by default (the Pallas kernels are TPU
-  programs; they execute on CPU only under ``interpret=True``, which is
-  for correctness tests, not speed). Set ``SCILIB_PALLAS=1`` to force the
-  interpreted kernels everywhere (used by the test suite).
+* Operands on a TPU -> Pallas kernels (compiled), except dtypes the MXU
+  lacks.
+* Operands on the CPU -> XLA reference by default (the Pallas kernels are
+  TPU programs; they execute on CPU only under ``interpret=True``, which
+  is for correctness tests, not speed). Set ``SCILIB_PALLAS=1`` to force
+  the interpreted kernels there (used by the test suite).
+
+The BLAS entry points choose when the call is lowered, for the platform
+of its operands, not for the default backend: on a TPU host the
+runtime's host path computes on the CPU device, and a TPU kernel cannot
+be lowered there.
 
 Precision mapping for the TPU target (DESIGN.md): BLAS ``s/c`` run native
 (f32/c64 — complex decomposes onto real MXU gemms); ``d/z`` have no MXU
@@ -21,7 +27,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import compat, ref
+from repro.kernels import ref
 from repro.kernels.attention import flash_attention
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.gemm import gemm as pallas_gemm
@@ -37,43 +43,49 @@ def use_pallas() -> bool:
     env = os.environ.get("SCILIB_PALLAS", "")
     if env == "0":
         return False
-    want = env == "1" or _backend() == "tpu"
-    if want and not compat.HAVE_PALLAS:
-        compat.warn_missing()       # degrade to ref, once per process
-        return False
-    return want
+    return env == "1" or _backend() == "tpu"
 
 
 def _interpret() -> bool:
     return _backend() != "tpu"
 
 
-def _mxu_dtype(dtype) -> bool:
-    return jnp.dtype(dtype) in (jnp.dtype(jnp.float32),
-                                jnp.dtype(jnp.bfloat16),
-                                jnp.dtype(jnp.float16),
-                                jnp.dtype(jnp.float64))
-    # f64 allowed only under interpret (CPU); the TPU check is below.
+def _by_platform(pallas_fn, ref_fn):
+    """The kernel for wherever the call is lowered: ``pallas_fn`` compiled
+    on a TPU; on any other platform ``ref_fn``, or ``pallas_fn``
+    interpreted under ``SCILIB_PALLAS=1``.  Only the chosen branch is
+    lowered, so a CPU lowering never sees the TPU kernel."""
+    env = os.environ.get("SCILIB_PALLAS", "")
+    if env == "0":
+        return ref_fn
+    other = (functools.partial(pallas_fn, interpret=True) if env == "1"
+             else ref_fn)
+    if _backend() != "tpu":
+        return other
+    tpu = functools.partial(pallas_fn, interpret=False)
+    return lambda *args: jax.lax.platform_dependent(*args, tpu=tpu,
+                                                    default=other)
 
 
 def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     """C = A @ B. Complex decomposes into real Pallas GEMMs (zgemm on the
     MXU via its real/imaginary planes — 4M algorithm)."""
-    if not use_pallas():
-        return ref.matmul(a, b)
-    interp = _interpret()
+    return _by_platform(_pallas_matmul, ref.matmul)(a, b)
+
+
+def _pallas_matmul(a, b, *, interpret):
     if jnp.issubdtype(a.dtype, jnp.complexfloating):
         ar, ai = jnp.real(a), jnp.imag(a)
         br, bi = jnp.real(b), jnp.imag(b)
-        f = functools.partial(_mm2d, interpret=interp)
+        f = functools.partial(_mm2d, interpret=interpret)
         rr = _batched(f, ar, br)
         ii = _batched(f, ai, bi)
         ri = _batched(f, ar, bi)
         ir = _batched(f, ai, br)
         return jax.lax.complex(rr - ii, ri + ir).astype(a.dtype)
-    if a.dtype == jnp.float64 and not interp:
+    if a.dtype == jnp.float64 and not interpret:
         return ref.matmul(a, b)      # no f64 MXU path
-    return _batched(functools.partial(_mm2d, interpret=interp), a, b)
+    return _batched(functools.partial(_mm2d, interpret=interpret), a, b)
 
 
 def _mm2d(a, b, interpret):
@@ -95,17 +107,20 @@ def _batched(f, a, b):
 
 def trsm(a: jax.Array, b: jax.Array, *, side: str = "L", uplo: str = "L",
          trans: str = "N", diag: str = "N") -> jax.Array:
-    if not use_pallas() or jnp.issubdtype(a.dtype, jnp.complexfloating):
+    kw = dict(side=side, uplo=uplo, trans=trans, diag=diag)
+    if jnp.issubdtype(a.dtype, jnp.complexfloating):
         # complex substitution needs complex VPU ops: XLA path (DESIGN.md)
-        return ref.trsm(a, b, side=side, uplo=uplo, trans=trans, diag=diag)
-    return pallas_trsm(a, b, side=side, uplo=uplo, trans=trans, diag=diag,
-                       interpret=_interpret())
+        return ref.trsm(a, b, **kw)
+    return _by_platform(functools.partial(pallas_trsm, **kw),
+                        functools.partial(ref.trsm, **kw))(a, b)
 
 
 def syrk(a: jax.Array, *, uplo: str = "L", trans: str = "N") -> jax.Array:
-    if not use_pallas() or jnp.issubdtype(a.dtype, jnp.complexfloating):
-        return ref.syrk(a, uplo=uplo, trans=trans)
-    return pallas_syrk(a, uplo=uplo, trans=trans, interpret=_interpret())
+    kw = dict(uplo=uplo, trans=trans)
+    if jnp.issubdtype(a.dtype, jnp.complexfloating):
+        return ref.syrk(a, **kw)
+    return _by_platform(functools.partial(pallas_syrk, **kw),
+                        functools.partial(ref.syrk, **kw))(a)
 
 
 def attention(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
@@ -173,7 +188,7 @@ def kernel_available(base: str, dtype, precision: str = "") -> bool:
 
 
 def _kernel_compiled() -> bool:
-    return _backend() == "tpu" and compat.HAVE_PALLAS
+    return _backend() == "tpu"
 
 
 def _block_kw(block: int, names=("bm", "bk", "bn")):

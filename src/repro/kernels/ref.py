@@ -14,11 +14,15 @@ import jax.numpy as jnp
 
 def matmul(a: jax.Array, b: jax.Array,
            out_dtype=None) -> jax.Array:
-    """C = A @ B with f32 accumulation (MXU convention)."""
+    """C = A @ B with f32 accumulation (MXU convention); f32 and
+    complex64 products at full f32 precision, as BLAS ``s``/``c`` promise
+    (a TPU's default is one bf16 pass)."""
     acc_t = jnp.float32
     if a.dtype in (jnp.float64, jnp.complex64, jnp.complex128):
         acc_t = a.dtype
-    out = jnp.matmul(a, b, preferred_element_type=acc_t)
+    full = a.dtype in (jnp.float32, jnp.complex64)
+    out = jnp.matmul(a, b, preferred_element_type=acc_t,
+                     precision=jax.lax.Precision.HIGHEST if full else None)
     return out.astype(out_dtype or a.dtype)
 
 

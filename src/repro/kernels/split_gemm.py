@@ -20,12 +20,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import precision
-from repro.kernels import compat
 from repro.kernels.gemm import gemm as pallas_gemm
 
 
 def _kernel_compiled() -> bool:
-    return jax.default_backend() == "tpu" and compat.HAVE_PALLAS
+    return jax.default_backend() == "tpu"
 
 
 def pass_mm(block: int = 0) -> precision.MatMul:

@@ -13,9 +13,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-from repro.kernels.compat import pl, pltpu
+from repro.kernels.gemm import fp32_precision
 
 
 def _syrk_kernel(a_ref, at_ref, o_ref, acc_ref, *, k_steps: int,
@@ -31,7 +32,8 @@ def _syrk_kernel(a_ref, at_ref, o_ref, acc_ref, *, k_steps: int,
     @pl.when(on_tri)
     def _update():
         acc_ref[...] += jnp.dot(a_ref[...], at_ref[...],
-                                preferred_element_type=acc_ref.dtype)
+                                preferred_element_type=acc_ref.dtype,
+                                precision=fp32_precision(a_ref.dtype))
 
     @pl.when(s == k_steps - 1)
     def _store():
@@ -64,7 +66,7 @@ def syrk(a: jax.Array, *, uplo: str = "L", trans: str = "N", bm: int = 256,
         out_specs=pl.BlockSpec((bm, bm), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((npad, npad), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bm), acc_dtype)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(opa, opa.mT)[:n, :n]

@@ -22,9 +22,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from repro.kernels.compat import pl
-from repro.kernels.gemm import gemm
+from jax.experimental import pallas as pl
+from repro.kernels.gemm import fp32_precision, gemm
 
 BASE = 128
 
@@ -39,20 +38,25 @@ def _trsm_base_kernel(l_ref, b_ref, x_ref, *, nb: int, unit: bool):
     x_ref[...] = b_ref[...]
     col = jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1)
 
-    def body(i, _):
-        l_row = pl.load(l_ref, (pl.dslice(i, 1), slice(None)))   # (1, nb)
+    def body(i, carry):
+        l_row = l_ref[pl.ds(i, 1), :]                              # (1, nb)
         l_masked = jnp.where(col < i, l_row, 0.0).astype(x_ref.dtype)
         partial = jnp.dot(l_masked, x_ref[...],
-                          preferred_element_type=x_ref.dtype)     # (1, bn)
-        b_row = pl.load(x_ref, (pl.dslice(i, 1), slice(None)))
-        upd = b_row - partial
+                          preferred_element_type=x_ref.dtype,
+                          precision=fp32_precision(x_ref.dtype))  # (1, bn)
+        upd = x_ref[pl.ds(i, 1), :] - partial
         if not unit:
-            diag = pl.load(l_ref, (pl.dslice(i, 1), pl.dslice(i, 1)))
-            upd = upd / diag[0, 0]
-        pl.store(x_ref, (pl.dslice(i, 1), slice(None)), upd)
-        return 0
+            # the diagonal entry, picked out of the row already loaded (a
+            # one-lane dynamic load is not tile-aligned)
+            diag = jnp.sum(jnp.where(col == i, l_row, 0.0), axis=1,
+                           keepdims=True)
+            upd = upd / diag.astype(x_ref.dtype)
+        x_ref[pl.ds(i, 1), :] = upd
+        return carry
 
-    jax.lax.fori_loop(0, nb, body, 0)
+    # int32 bounds: Mosaic has no 64-bit index (jax_enable_x64 would
+    # otherwise make the loop counter int64)
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(nb), body, jnp.int32(0))
 
 
 @functools.partial(jax.jit, static_argnames=("unit", "bn", "interpret"))
@@ -66,11 +70,12 @@ def _trsm_base(l: jax.Array, b: jax.Array, *, unit: bool, bn: int = 256,
     out = pl.pallas_call(
         functools.partial(_trsm_base_kernel, nb=nb, unit=unit),
         grid=grid,
+        # int32 block indices (a literal 0 is int64 under x64)
         in_specs=[
-            pl.BlockSpec((nb, nb), lambda j: (0, 0)),
-            pl.BlockSpec((nb, bn), lambda j: (0, j)),
+            pl.BlockSpec((nb, nb), lambda j: (j * 0, j * 0)),
+            pl.BlockSpec((nb, bn), lambda j: (j * 0, j)),
         ],
-        out_specs=pl.BlockSpec((nb, bn), lambda j: (0, j)),
+        out_specs=pl.BlockSpec((nb, bn), lambda j: (j * 0, j)),
         out_shape=jax.ShapeDtypeStruct(bp.shape, b.dtype),
         interpret=interpret,
     )(l, bp)

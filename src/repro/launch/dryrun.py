@@ -75,14 +75,6 @@ def _n_groups(cfg) -> int:
     return cfg.n_layers // period_of(cfg)
 
 
-def _cost_dict(cost) -> dict:
-    """Normalize cost_analysis() across JAX versions: newer releases
-    return one dict, 0.4.x returns a one-element list of dicts."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def _cost_probe(cfg, shape_name: str, mesh, k: int, **cell_kw) -> dict:
     """Compile the k-group variant UNROLLED (true per-layer costs);
     return per-device cost + collective bytes."""
@@ -94,7 +86,7 @@ def _cost_probe(cfg, shape_name: str, mesh, k: int, **cell_kw) -> dict:
                      donate_argnums=cell.donate_argnums)
     compiled = jitted.lower(*cell.args).compile()
     try:
-        cost = _cost_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis() or {}
     except Exception:
         cost = {}
     colls = collective_bytes(compiled.as_text())
@@ -164,12 +156,12 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
             except Exception as e:          # CPU backend may lack this
                 rec["memory"] = {"error": str(e)}
             try:
-                cost = _cost_dict(compiled.cost_analysis())
+                cost = compiled.cost_analysis() or {}
             except Exception:
                 cost = None
             if not cost or "flops" not in (cost or {}):
                 try:
-                    cost = _cost_dict(lowered.cost_analysis())
+                    cost = lowered.cost_analysis() or {}
                 except Exception:
                     cost = cost or {}
             hlo = compiled.as_text()

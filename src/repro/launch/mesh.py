@@ -12,16 +12,25 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the model stack places
+    activations with ``with_sharding_constraint`` and lets the compiler
+    resolve gathers, which explicit axes (``jax.make_mesh``'s default)
+    refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int = 8, model: int = 2):
     """Small mesh over however many (fake) devices a test session has."""
     data = n_devices // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:
@@ -37,7 +46,7 @@ def offload_devices():
     The runtime enumerates N device tiers (``SCILIB_DEVICES`` or
     ``len(jax.devices())``, see ``repro.core.memspace``); tier *i* maps to
     real device ``i % len(jax.devices())`` — with more tiers than
-    hardware (the CPU container's simulated layout) tiers wrap onto the
+    hardware (``SCILIB_DEVICES`` above the device count) tiers wrap onto the
     same physical device, exactly like :func:`memspace.put_block`.
     """
     from repro.core import memspace

@@ -162,12 +162,8 @@ def moe_fwd_a2a(p: Params, cfg: ModelConfig, x: jax.Array, mesh,
     device instead of resharding gathers. Per-device capacity semantics
     (standard for EP).
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _sm
-        shard_map = _sm.shard_map if hasattr(_sm, "shard_map") else _sm
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
 
     e, k = cfg.n_experts, cfg.top_k
     m_sz = mesh.shape[model_axis]

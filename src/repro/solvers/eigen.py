@@ -21,6 +21,7 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from scipy.linalg import eigh_tridiagonal
 
 from repro.core import blas
 from repro.core.lapack import DEFAULT_NB
@@ -121,14 +122,7 @@ def _tridiag_eigh(d: np.ndarray, e: np.ndarray
                   ) -> Tuple[np.ndarray, np.ndarray]:
     """Host eigensolve of the real tridiagonal (O(n) data: far below
     any offload threshold, exactly where LAPACK keeps it too)."""
-    try:
-        from scipy.linalg import eigh_tridiagonal
-        return eigh_tridiagonal(d, e)
-    except ImportError:                        # pragma: no cover
-        t = np.diag(d)
-        if e.size:
-            t = t + np.diag(e, 1) + np.diag(e, -1)
-        return np.linalg.eigh(t)
+    return eigh_tridiagonal(d, e)
 
 
 def _larft(V: jax.Array, taus: List[complex]) -> np.ndarray:

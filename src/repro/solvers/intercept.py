@@ -3,9 +3,8 @@
 The paper's tool patches LAPACK entry points (``zgetrf_``, ``zpotrf_``,
 ``zheev_`` ...) exactly like BLAS ones; the JAX equivalents are the
 public factorization/solve symbols application code actually calls:
-``jnp.linalg.cholesky``/``solve`` (+ ``lu`` where the jax version has
-one) and ``jax.scipy.linalg.lu_factor``/``lu_solve``/``cho_factor``/
-``cho_solve``/``solve_triangular``/``eigh``.  The trampolines route
+``jnp.linalg.cholesky``/``solve`` and ``jax.scipy.linalg.lu_factor``/
+``lu_solve``/``cho_factor``/``cho_solve``/``solve_triangular``/``eigh``.  The trampolines route
 eager, super-threshold, float/complex square systems onto the
 span-wrapped blocked drivers (:mod:`repro.solvers.drivers`) — same
 gating discipline as the matmul trampolines in
@@ -95,14 +94,6 @@ def _solve(a, b):
     return _fall("solve", a, b)
 
 
-def _lu(a):                                    # pragma: no cover - no
-    if _solvable(a):                           # jnp.linalg.lu on 0.4.x
-        lu, piv = drivers.getrf(a)
-        return piv, jnp.tril(lu, -1) + jnp.eye(a.shape[0], dtype=a.dtype), \
-            jnp.triu(lu)
-    return _fall("lu", a)
-
-
 def _lu_factor(a, overwrite_a=False, check_finite=True):
     if _solvable(a):
         return drivers.getrf(a)
@@ -174,7 +165,7 @@ _SYMBOLS = (
     (jsl, "cho_solve", _cho_solve),
     (jsl, "solve_triangular", _solve_triangular),
     (jsl, "eigh", _eigh),
-) + ((jnp.linalg, "lu", _lu),) * hasattr(jnp.linalg, "lu")
+)
 
 
 def patch_symbols() -> None:

@@ -22,6 +22,22 @@ def test_lsms_mini_physics_under_offload():
     assert out["n_solves"] == 4
 
 
+def test_lsms_complex64_compiles_nothing_complex128(caplog):
+    """A TPU's compiler aborts on any complex128 operand, and under x64 a
+    Python complex enters an eager op as one: LSMS run in complex64, its
+    solves offloaded, must compile no computation that takes complex128."""
+    jax.clear_caches()
+    with jax.log_compiles(True), caplog.at_level("WARNING"), \
+            core.offload("dfu", threshold=40):
+        out = lsms.run_mini(atoms=1, energies=2, scf=1, n=80, nb=16,
+                            dtype="complex64")
+    compiled = [r.getMessage() for r in caplog.records
+                if "Compiling" in r.getMessage()]
+    assert compiled
+    assert not [m for m in compiled if "complex128" in m]
+    assert out["n_solves"] == 2 and out["max_ratio"] < 30
+
+
 def test_parsec_mini_ritz_values():
     out = dft.run_mini(ngrid=512, nstates=16, scf=8)
     assert out["max_err_low_half"] < 1e-6

@@ -28,20 +28,40 @@ def test_memspace_probe_matches_backend():
     kinds = [m.kind for m in jax.devices()[0].addressable_memories()]
     assert ms.device_kind in kinds
     assert ms.host_kind in kinds
-    # simulated exactly when the backend can't express two tiers
-    assert ms.simulated == (ms.host_kind == ms.device_kind)
+    # the installed backend expresses two real tiers
+    assert ms.host != ms.device and ms.host_kind != ms.device_kind
+
+
+def test_probe_refuses_backend_without_two_placements(monkeypatch):
+    """No simulated fallback: a CPU device without an unpinned_host kind,
+    or an accelerator with no CPU backend beside it, is refused."""
+    import types
+
+    import jax
+
+    one_kind = types.SimpleNamespace(
+        platform="cpu",
+        addressable_memories=lambda: [types.SimpleNamespace(kind="device")])
+    with pytest.raises(RuntimeError, match="unpinned_host"):
+        memspace.probe(device=one_kind, n_devices=1)
+
+    def no_cpu(backend=None):
+        raise RuntimeError(f"Unknown backend {backend}")
+
+    monkeypatch.setattr(jax, "devices", no_cpu)
+    with pytest.raises(RuntimeError, match="no CPU backend"):
+        memspace.probe(device=types.SimpleNamespace(platform="tpu"),
+                       n_devices=1)
 
 
 def test_simulated_tiers_track_identity_and_movement():
-    ms = memspace.active()
     x = host_array(_f32((32, 32)))
     assert memspace.tier_of(x) == memspace.HOST
     y = memspace.put(x, memspace.DEVICE)
     assert memspace.tier_of(y) == memspace.DEVICE
     # the source keeps its own tier: Mem-Copy round trips stay observable
     assert memspace.tier_of(x) == memspace.HOST
-    if ms.simulated:
-        assert y is not x
+    assert y is not x             # a real transfer, to a new buffer
     # same-tier put is the identity (no spurious copies on the fast path)
     assert memspace.put(y, memspace.DEVICE) is y
     # untagged fresh arrays behave device-resident, like on accelerators
@@ -49,8 +69,8 @@ def test_simulated_tiers_track_identity_and_movement():
 
 
 def test_single_kind_backend_runs_all_policies():
-    """On this container the backend has one memory kind; every policy
-    must still run and count movement (the 51-failing-seed-tests fix)."""
+    """Every policy runs over the backend's real tiers and counts its
+    movement."""
     a_np, b_np = _f32((300, 300)), _f32((300, 300))
     for pol in ("cpu", "memcopy", "counter", "dfu", "pinned"):
         with core.offload(pol, threshold=100) as rt:

@@ -44,8 +44,6 @@ with mesh, axis_env(axis_env_for(mesh)):
     lowered = jitted.lower(*cell.args)
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
 print(json.dumps({"ok": True, "flops": float(cost.get("flops", 0))}))
 """
 

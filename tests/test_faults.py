@@ -316,13 +316,17 @@ def test_quarantine_reshard_recover():
                             faults="transfer:p=1,device=1,seed=1",
                             retries=0, breaker=2,
                             breaker_cooldown_ms=50.0)
+        # operands and host references made up front: the calls between
+        # a trip and the next call must fit in the 50 ms cooldown, and
+        # a host BLAS reference can take longer than that on its own
+        pairs = [(_mat(384), _mat(384)) for _ in range(4)]
+        refs = [a @ b for a, b in pairs]
         with Session(cfg, record_trace=False, intercept=False) as s:
             rt = s.runtime
-            refs, outs = [], []
+            outs = []
 
             def call():
-                a, b = _mat(384), _mat(384)
-                refs.append(a @ b)
+                a, b = pairs[len(outs)]
                 outs.append(np.asarray(
                     blas.gemm(host_array(a), host_array(b))))
 

@@ -70,6 +70,22 @@ def test_put_block_tags_device_index():
         assert memspace.put_block(y, 2) is y      # same-home is identity
 
 
+def test_sharded_result_mixes_with_host_data():
+    """A sharded call hands its result back uncommitted, like a whole-call
+    DEVICE placement: user code can combine it with a host_array (on a
+    TPU, committed tiles would pin it to one chip and such an op would
+    be refused)."""
+    import jax.numpy as jnp
+    a_np, b_np = _mat(512), _mat(512)
+    with devices(4), core.offload("dfu", threshold=50) as rt:
+        a, b = host_array(a_np), host_array(b_np)
+        c = jnp.matmul(a, b)
+        assert rt.stats.per_routine["sgemm"].sharded == 1
+        assert not c.committed
+        d = np.asarray(c + a)
+    np.testing.assert_allclose(d, a_np @ b_np + a_np, rtol=1e-4, atol=1e-3)
+
+
 # --------------------------------------------------------------------- #
 # tile decomposition correctness vs the single-device path               #
 # --------------------------------------------------------------------- #
