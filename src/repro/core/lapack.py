@@ -21,6 +21,7 @@ import numpy as np
 from repro.core import blas
 from repro.core import memspace
 from repro.core import runtime as rtm
+from repro.core import trace as trc
 
 DEFAULT_NB = 128
 
@@ -48,8 +49,9 @@ def _store(factor: jax.Array, idx, block: jax.Array) -> jax.Array:
     """``factor.at[idx].set(block)`` with the block first brought to the
     factor's tier: a panel factored on the host, or an update the runtime
     kept on the host, must not drag the whole factor off the device."""
-    return factor.at[idx].set(
-        memspace.put(block, memspace.tier_of(factor), check=False))
+    with trc.span(trc.LU_STORE):
+        return factor.at[idx].set(
+            memspace.put(block, memspace.tier_of(factor), check=False))
 
 
 @jax.jit
@@ -110,16 +112,18 @@ def getrf(a: jax.Array, nb: int = DEFAULT_NB
     for j0 in range(0, k_max, nb):
         jb = min(nb, k_max - j0)
         panel = lu[j0:, j0:j0 + jb]
-        fpanel, lpiv = _pivot_panel(panel)
+        with trc.span(trc.LU_PANEL):
+            fpanel, lpiv = _pivot_panel(panel)
         _note_panel(prec, m - j0, jb, fpanel)
         # apply local pivots to the whole rows (left + right of panel)
-        perm = np.arange(m - j0)
-        for jj in range(jb):           # compose swaps (host loop, nb small)
-            r = lpiv[jj]
-            perm[jj], perm[r] = perm[r], perm[jj]
-        abs_perm = np.concatenate([np.arange(j0), perm + j0])
-        lu = lu[abs_perm]
-        piv = piv[abs_perm]
+        with trc.span(trc.LU_PERMUTE):
+            perm = np.arange(m - j0)
+            for jj in range(jb):       # compose swaps (host loop, nb small)
+                r = lpiv[jj]
+                perm[jj], perm[r] = perm[r], perm[jj]
+            abs_perm = np.concatenate([np.arange(j0), perm + j0])
+            lu = lu[abs_perm]
+            piv = piv[abs_perm]
         lu = _store(lu, (slice(j0, None), slice(j0, j0 + jb)), fpanel)
         if j0 + jb < n:
             # U12 = L11^{-1} A12           (trsm, unit lower)

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 import weakref
 from contextvars import ContextVar
 from typing import Callable, Dict, Optional, Tuple
@@ -46,6 +47,7 @@ import numpy as np
 from jax.sharding import Sharding, SingleDeviceSharding
 
 from repro.core import faults as flt
+from repro.core import trace as trc
 
 #: logical tier names (stable across backends)
 HOST = "host"
@@ -68,6 +70,12 @@ DEVICE = "device"
 _FAULT_HOOK: ContextVar[Optional[Callable[[Optional[int], int], None]]] = (
     ContextVar("scilib_fault_hook", default=None))
 
+#: (direction "h2d"/"d2h", nbytes, host seconds) -> None: the active
+#: runtime's link counters, fed by every real move between the host and
+#: a device tier (routed like the fault hook)
+_LINK_HOOK: ContextVar[Optional[Callable[[str, int, float], None]]] = (
+    ContextVar("scilib_link_hook", default=None))
+
 #: SCILIB_DEBUG level, plumbed in by the owning runtime (config boundary)
 _DEBUG: ContextVar[int] = ContextVar("scilib_debug", default=0)
 
@@ -89,6 +97,28 @@ def set_fault_hook(hook: Optional[Callable[[Optional[int], int], None]],
     offload-path faults they cannot fall back from.  Context-local:
     one thread's injector never fires in another thread's transfers."""
     _FAULT_HOOK.set(hook)
+
+
+def set_link_hook(hook: Optional[Callable[[str, int, float], None]],
+                  ) -> None:
+    """Install (or clear, with None) the link counter hook; the runtime
+    layer points it at the active runtime.  Context-local."""
+    _LINK_HOOK.set(hook)
+
+
+def _link(direction: str, nbytes: int, move: Callable[[], jax.Array]
+          ) -> jax.Array:
+    """Run one move over the host link under its span, and count its
+    bytes and the host seconds the transfer call takes.  Nothing here
+    waits for the copy: a transfer that returns before its data has
+    landed is waited for by whoever reads the result."""
+    t0 = time.perf_counter()
+    with trc.span(trc.LINK_H2D if direction == "h2d" else trc.LINK_D2H):
+        out = move()
+    hook = _LINK_HOOK.get()
+    if hook is not None:
+        hook(direction, nbytes, time.perf_counter() - t0)
+    return out
 
 
 def set_debug(level: int) -> None:
@@ -335,6 +365,9 @@ def put(x: jax.Array, tier: str, *, check: bool = True) -> jax.Array:
     :class:`repro.core.faults.TransferError` / ``DeviceOOMError`` the
     runtime's retry/fallback guard can absorb.  ``check=False`` opts a
     call site out of injection (host-path streaming, explicit pins).
+
+    A move of an array between the tiers is link traffic, counted by
+    direction; host data (numpy) first placed on HOST is not.
     """
     if tier_of(x) == tier:
         return x
@@ -342,7 +375,10 @@ def put(x: jax.Array, tier: str, *, check: bool = True) -> jax.Array:
     if check and tier == DEVICE and hook is not None:
         hook(None, x.nbytes)
     try:
-        return _move(x, tier)
+        if not isinstance(x, jax.Array):
+            return _move(x, tier)
+        return _link("h2d" if tier == DEVICE else "d2h", x.nbytes,
+                     lambda: _move(x, tier))
     except _MOVE_ERRORS as exc:
         raise _wrap_move_error(exc, device=None, nbytes=x.nbytes) from exc
 
@@ -360,7 +396,8 @@ def put_block(x: jax.Array, device: int) -> jax.Array:
     Like :func:`put`, a movement is a transfer guard site — the fault
     hook fires first (with the device index, so ``device=`` rules in
     ``SCILIB_FAULTS`` target one tier), and movement failures raise the
-    typed hierarchy.
+    typed hierarchy.  A block that comes from the host is link traffic;
+    one copied from another device tier is not.
     """
     real = jax.devices()
     ms = active()
@@ -372,7 +409,11 @@ def put_block(x: jax.Array, device: int) -> jax.Array:
         hook(device, x.nbytes)
     target = real[device % len(real)] if len(real) > 1 else ms.device
     try:
-        moved = jax.device_put(x, target, may_alias=False)
+        if isinstance(x, jax.Array) and tier_of(x) == DEVICE:
+            moved = jax.device_put(x, target, may_alias=False)
+        else:
+            moved = _link("h2d", x.nbytes, lambda: jax.device_put(
+                x, target, may_alias=False))
     except _MOVE_ERRORS as exc:
         raise _wrap_move_error(exc, device=device,
                                nbytes=x.nbytes) from exc

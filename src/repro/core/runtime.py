@@ -79,6 +79,7 @@ to the flat dispatch it replaced.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import threading
 import time
@@ -93,6 +94,7 @@ from repro.core import memspace
 from repro.core import precision as prec
 from repro.core import residency as res
 from repro.core import threshold as thr
+from repro.core import trace as trc
 from repro.core.config import OffloadConfig
 from repro.core.policy import CounterPolicy, PolicyBase, make_policy
 from repro.core.trace import Trace
@@ -202,6 +204,10 @@ class CallContext:
     # the offload path's placed operands (set by _offload_whole), which
     # the split residual check and an escalated rerun reuse
     placed: Optional[list] = None
+    # host seconds of the call's stages: canonicalize + decide, and
+    # operand / output placement (a part of the call's ``seconds``)
+    decide_s: float = 0.0
+    place_s: float = 0.0
 
 
 @dataclasses.dataclass
@@ -231,7 +237,12 @@ class RoutineStats:
     calls: int = 0
     offloaded: int = 0
     on_host: int = 0
+    # host seconds from the plan to the submitted result; the decision
+    # before it is ``decide_seconds``, the placement in it
+    # ``place_seconds``
     seconds: float = 0.0
+    decide_seconds: float = 0.0
+    place_seconds: float = 0.0
     flops: float = 0.0
     bytes_in: int = 0
     bytes_out: int = 0
@@ -311,6 +322,13 @@ class RuntimeStats:
     fallbacks: int = 0         # calls completed on host after a failure
     quarantines: int = 0       # breaker trips (incl. half-open re-trips)
     recoveries: int = 0        # quarantined devices re-admitted
+    # moves over the host link, per direction, counted where memspace
+    # makes them (BLAS operands and the LU's panel round trips alike):
+    # bytes, and host seconds inside the transfer call
+    link_h2d_bytes: int = 0
+    link_h2d_seconds: float = 0.0
+    link_d2h_bytes: int = 0
+    link_d2h_seconds: float = 0.0
     # per-call-site profiles (shared with the owning runtime's registry)
     callsites: Optional[cs.CallSiteRegistry] = None
     # the owning runtime's per-device circuit breaker (health section)
@@ -333,16 +351,23 @@ class RuntimeStats:
         return sum(r.bytes_in + r.bytes_out
                    for r in self.per_routine.values())
 
-    def reuse_ratio(self) -> float:
-        hits = sum(r.cache_hits for r in self.per_routine.values())
-        miss = sum(r.cache_misses for r in self.per_routine.values())
-        return hits / max(1, miss)
-
-    def dispatch_hit_ratio(self) -> float:
-        hits = sum(r.dispatch_hits for r in self.per_routine.values())
-        total = hits + sum(r.dispatch_misses
-                           for r in self.per_routine.values())
-        return hits / max(1, total)
+    def flat(self) -> Dict[str, float]:
+        """Every numeric counter in one flat dict, for taking window
+        differences: top-level counters under their field names, rows
+        as ``<routine>.<field>``, ``dev<i>.<field>`` and
+        ``solver.<name>.<field>``."""
+        def numbers(obj, prefix=""):
+            return {prefix + f.name: getattr(obj, f.name)
+                    for f in dataclasses.fields(obj)
+                    if type(getattr(obj, f.name)) in (int, float)}
+        out = numbers(self)
+        for name, r in self.per_routine.items():
+            out.update(numbers(r, f"{name}."))
+        for dev, d in self.per_device.items():
+            out.update(numbers(d, f"dev{dev}."))
+        for name, sv in self.solvers.items():
+            out.update(numbers(sv, f"solver.{name}."))
+        return out
 
     def report(self) -> str:
         lines = ["scilib-accel runtime report",
@@ -357,6 +382,10 @@ class RuntimeStats:
                          f"{r.on_host:>7}{r.seconds:>10.3f}{gb:>10.3f}"
                          f"{reuse:>8.1f}{dhit:>7.2f}")
         lines.append(f"uninstrumented calls: {self.uninstrumented_calls}")
+        lines.append(f"link: h2d {self.link_h2d_bytes / 1e9:.3f} GB in "
+                     f"{self.link_h2d_seconds:.3f} s, d2h "
+                     f"{self.link_d2h_bytes / 1e9:.3f} GB in "
+                     f"{self.link_d2h_seconds:.3f} s")
         if self.platforms:
             lines.append("platforms: " + " ".join(
                 f"{path}={','.join(sorted(p))}"
@@ -746,6 +775,18 @@ class OffloadRuntime:
             self.trace.record_event(kind, store, nbytes,
                                     session=self.session_id)
 
+    def _count_link(self, direction: str, nbytes: int,
+                    seconds: float) -> None:
+        """memspace's link hook: one move over the host link."""
+        with self._stats_lock:
+            st = self.stats
+            if direction == "h2d":
+                st.link_h2d_bytes += nbytes
+                st.link_h2d_seconds += seconds
+            else:
+                st.link_d2h_bytes += nbytes
+                st.link_d2h_seconds += seconds
+
     def _on_placement_evict(self, key, placed, nbytes: int) -> None:
         """Cap pressure pushed a placement out: the registry forgets it, so
         the source's next use re-migrates (and is counted again).  JAX
@@ -1044,8 +1085,8 @@ class OffloadRuntime:
             store.put(key, placed, placed.nbytes, anchor=op.parent)
         return placed, moved, False
 
-    def _sharded_call(self, st: RoutineStats, plan: TilePlan,
-                      site: Optional[cs.CallSiteProfile] = None,
+    def _sharded_call(self, call: CallContext, st: RoutineStats,
+                      plan: TilePlan,
                       ) -> Tuple[jax.Array, Tuple[int, ...]]:
         """Execute one call as scheduled tiles and gather the output.
 
@@ -1065,28 +1106,32 @@ class OffloadRuntime:
             self._sched_load[dev] += 1
             devices.append(dev)
         # Phase 2 — place blocks and run the tile kernels.
+        site = call.site
         outs = []
         for tile, dev in zip(plan.tiles, devices):
             dst = self.stats.device(dev)
             placed = []
-            for op in tile.ops:
-                arr, moved, hit = self._place_block(dev, op, st)
-                st.bytes_in += moved
-                dst.moved_bytes += moved
-                st.cache_hits += int(hit)
-                st.cache_misses += int(not hit)
-                dst.affinity_hits += int(hit)
-                if site is not None:
-                    site.observe_residency(hit)
-                placed.append(arr)
-            outs.append(self._guarded(
-                "kernel", lambda t=tile, p=placed: t.compute(*p),
-                device=dev, nbytes=0, st=st))
+            with self._placing(call):
+                for op in tile.ops:
+                    arr, moved, hit = self._place_block(dev, op, st)
+                    st.bytes_in += moved
+                    dst.moved_bytes += moved
+                    st.cache_hits += int(hit)
+                    st.cache_misses += int(not hit)
+                    dst.affinity_hits += int(hit)
+                    if site is not None:
+                        site.observe_residency(hit)
+                    placed.append(arr)
+            with trc.span(trc.SUBMIT):
+                outs.append(self._guarded(
+                    "kernel", lambda t=tile, p=placed: t.compute(*p),
+                    device=dev, nbytes=0, st=st))
             dst.tiles += 1
         # tiles computed on several real devices meet on the whole-call
         # device before the gather (one op never spans devices)
-        out = memspace.uncommitted(
-            plan.gather([memspace.gather_home(o) for o in outs]))
+        with trc.span(trc.SUBMIT):
+            out = memspace.uncommitted(
+                plan.gather([memspace.gather_home(o) for o in outs]))
         if self.policy.persistent:
             for tile, dev, block in zip(plan.tiles, devices, outs):
                 self.block_stores[dev].put(
@@ -1094,7 +1139,8 @@ class OffloadRuntime:
                     anchor=out)
         if self.policy.copy_back:
             st.bytes_out += out.nbytes
-            out = memspace.put(out, memspace.HOST)
+            with self._placing(call):
+                out = memspace.put(out, memspace.HOST)
         st.offloaded += 1
         st.sharded += 1
         st.tiles += len(plan.tiles)
@@ -1220,18 +1266,22 @@ class OffloadRuntime:
             # the offload decision is static and the compute fn embeds it.
             return compute(*arrays)
 
-        call = self._canonicalize(routine, m, n, k, operands, arrays,
-                                  compute, batch, key, shard,
-                                  kernel_compute, split_compute,
-                                  split_check)
-        decision = self._decide(call, st)
+        t = time.perf_counter()
+        with trc.span(trc.DECIDE):
+            call = self._canonicalize(routine, m, n, k, operands, arrays,
+                                      compute, batch, key, shard,
+                                      kernel_compute, split_compute,
+                                      split_check)
+            decision = self._decide(call, st)
         t0 = time.perf_counter()
+        call.decide_s = t0 - t
         self._stage_plan(call, decision)
         out, devices = self._execute(call, decision, st)
         if decision.precision and decision.offload:
             # sampled-residual check + escalation; inside the timed
             # window so probe samples bill what the scheme really costs
-            out = self._verify_split(call, decision, st, out)
+            with trc.span(trc.SUBMIT):
+                out = self._verify_split(call, decision, st, out)
         if self.sync_mode or decision.timed:
             # adaptive probes always block: path timing needs wall time
             out.block_until_ready()
@@ -1462,8 +1512,7 @@ class OffloadRuntime:
             return self._run_host(call, st), ()
         try:
             if decision.plan is not None:
-                return self._sharded_call(st, decision.plan,
-                                          site=call.site)
+                return self._sharded_call(call, st, decision.plan)
             return self._offload_whole(call, decision, st), ()
         except flt.OffloadError as exc:
             return self._fallback_host(call, decision, st, exc), ()
@@ -1474,8 +1523,51 @@ class OffloadRuntime:
         """Single-device offload: the policy places every operand.
         Each operand movement and the kernel launch are separate
         guarded units, attributed to the whole-call device tier."""
-        site = call.site
         dev = self._whole_device()
+        with self._placing(call):
+            args = call.placed = self._place_operands(call, st, dev)
+        # venue selection: the pallas-venue arithmetic replaces the
+        # generic jitted compute inside the *same* guarded kernel unit,
+        # so injection, retries and breaker trips cover it identically.
+        # A split decision swaps in the split formulation the same way
+        # (bound to the decided scheme and venue).
+        if decision.precision and call.split_compute is not None:
+            compute = call.split_compute(decision.precision,
+                                         decision.venue)
+        elif (decision.venue == "pallas"
+                and call.kernel_compute is not None):
+            compute = call.kernel_compute
+        else:
+            compute = call.compute
+        with trc.span(trc.SUBMIT):
+            out = self._guarded("kernel", lambda: compute(*args),
+                                device=dev, nbytes=0, st=st)
+        with self._placing(call):
+            out_p = self._guarded(
+                "transfer", lambda: self.policy.place_output(self, out),
+                device=dev, nbytes=out.nbytes, st=st)
+        st.bytes_out += out_p.moved_bytes
+        st.offloaded += 1
+        return out_p.array
+
+    @staticmethod
+    @contextlib.contextmanager
+    def _placing(call: CallContext):
+        """A placement stage of ``call``: under its span, its host
+        seconds added to the call's ``place_s``."""
+        t = time.perf_counter()
+        try:
+            with trc.span(trc.PLACE):
+                yield
+        finally:
+            call.place_s += time.perf_counter() - t
+
+    def _place_operands(self, call: CallContext, st: RoutineStats,
+                        dev: int) -> list:
+        """The policy places every operand of a whole-call offload,
+        each movement a guarded unit attributed to device tier ``dev``;
+        returns them harmonized onto the device tier."""
+        site = call.site
         placed, budget_used = [], 0
         ai = self._arith_intensity(call.routine, call.m, call.n, call.k,
                                    call.arrays, call.batch)
@@ -1506,28 +1598,7 @@ class OffloadRuntime:
             placed.append(p.array)
         # harmonize outside the kernel guard: a retried kernel must not
         # re-bill transient streaming bytes
-        args = call.placed = self._harmonize(placed, st)
-        # venue selection: the pallas-venue arithmetic replaces the
-        # generic jitted compute inside the *same* guarded kernel unit,
-        # so injection, retries and breaker trips cover it identically.
-        # A split decision swaps in the split formulation the same way
-        # (bound to the decided scheme and venue).
-        if decision.precision and call.split_compute is not None:
-            compute = call.split_compute(decision.precision,
-                                         decision.venue)
-        elif (decision.venue == "pallas"
-                and call.kernel_compute is not None):
-            compute = call.kernel_compute
-        else:
-            compute = call.compute
-        out = self._guarded("kernel", lambda: compute(*args),
-                            device=dev, nbytes=0, st=st)
-        out_p = self._guarded(
-            "transfer", lambda: self.policy.place_output(self, out),
-            device=dev, nbytes=out.nbytes, st=st)
-        st.bytes_out += out_p.moved_bytes
-        st.offloaded += 1
-        return out_p.array
+        return self._harmonize(placed, st)
 
     def _verify_split(self, call: CallContext,
                       decision: DispatchDecision, st: RoutineStats,
@@ -1569,6 +1640,8 @@ class OffloadRuntime:
                 out: jax.Array, devices: Tuple[int, ...], dt: float,
                 st: RoutineStats) -> None:
         st.seconds += dt
+        st.decide_seconds += call.decide_s
+        st.place_seconds += call.place_s
         path = "offload" if decision.offload else "host"
         plats = self.stats.platforms.setdefault(path, set())
         plats.update(d.platform for d in out.devices())
@@ -1597,6 +1670,8 @@ class OffloadRuntime:
         self._record_trace(call.routine, call.m, call.n, call.k,
                            call.operands, out, call.batch, devices,
                            site_id=call.site_id, seconds=dt,
+                           decide_seconds=call.decide_s,
+                           place_seconds=call.place_s,
                            venue=decision.venue,
                            precision=decision.precision,
                            solver_id=solver_id)
@@ -1612,8 +1687,10 @@ class OffloadRuntime:
     def _run_host(self, call: CallContext, st: RoutineStats) -> jax.Array:
         """The host path: every operand on the HOST tier, the arithmetic
         on the host CPU, the result left there (the paper's CPU BLAS)."""
-        out = call.compute(*self._harmonize(call.arrays, st, memspace.HOST))
-        return memspace.put(out, memspace.HOST, check=False)
+        with trc.span(trc.HOST_CALL):
+            out = call.compute(*self._harmonize(call.arrays, st,
+                                                memspace.HOST))
+            return memspace.put(out, memspace.HOST, check=False)
 
     def _harmonize(self, arrays, st: Optional[RoutineStats] = None,
                    tier: str = memspace.DEVICE) -> list:
@@ -1650,7 +1727,8 @@ class OffloadRuntime:
 
     def _record_trace(self, routine, m, n, k, operands, out, batch,
                       devices=(), site_id: str = "",
-                      seconds: float = 0.0, venue: str = "",
+                      seconds: float = 0.0, decide_seconds: float = 0.0,
+                      place_seconds: float = 0.0, venue: str = "",
                       precision: str = "", solver_id: str = "") -> None:
         if self.trace is None:
             return
@@ -1675,6 +1753,7 @@ class OffloadRuntime:
             routine=routine, m=m, n=n, k=k, batch=batch,
             operands=tuple(ops), devices=tuple(devices),
             callsite_id=site_id, seconds=seconds,
+            decide_seconds=decide_seconds, place_seconds=place_seconds,
             out_buf=out_buf, out_nbytes=out_nbytes, venue=venue,
             precision=precision, solver_id=solver_id))
 
@@ -1697,9 +1776,11 @@ def activate(runtime: Optional[OffloadRuntime]) -> None:
     _ACTIVE.set(runtime)
     if runtime is None:
         memspace.set_fault_hook(None)
+        memspace.set_link_hook(None)
         memspace.set_debug(0)
     else:
         memspace.set_fault_hook(runtime._transfer_fault_hook())
+        memspace.set_link_hook(runtime._count_link)
         memspace.set_debug(runtime.debug)
 
 

@@ -52,6 +52,7 @@ import threading
 from contextvars import ContextVar
 from typing import List, Optional, Tuple
 
+from repro.core import trace as trc
 from repro.core.config import OffloadConfig
 
 __all__ = ["Session", "session", "active_session"]
@@ -149,6 +150,7 @@ class Session:
         _STACK.set(_STACK.get() + (self,))
         with _OPEN_LOCK:
             _OPEN.append(self)
+        trc.gc_spans(True)
         rt.activate(self.runtime)
         if self.intercept:
             icp.patch_symbols()
@@ -177,6 +179,7 @@ class Session:
         with _OPEN_LOCK:
             if self in _OPEN:
                 _OPEN.remove(self)
+        trc.gc_spans(False)
         if self.intercept:
             if self._lapack_patched:
                 from repro.solvers import intercept as slv

@@ -5,16 +5,84 @@ with operand identities (so reuse is visible) but no array payloads. The
 interception layer records traces; the memtier simulator replays them under
 different data-movement policies with calibrated hardware constants — the
 methodology behind Tables 3 and 5 of the paper.
+
+The runtime also marks where its time goes with profiler spans
+(``jax.profiler.TraceAnnotation``): fixed names under the prefix
+``scilib.``, listed in :data:`SPANS`.  They land on the profiler's host
+plane, on the same clock as the device planes, so a trace names what
+the host was doing while the device sat idle.  With no profiler
+recording, a span records nothing.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import os
 import threading
 from typing import Dict, Iterator, List, Optional, Tuple
 
+import jax
+
 from repro.core.residency import ResidencyEvent
+
+# --------------------------------------------------------------------- #
+# profiler spans                                                         #
+# --------------------------------------------------------------------- #
+#: the dispatch pipeline: ``_canonicalize`` + ``_decide``
+DECIDE = "scilib.decide"
+#: operand placement, harmonization and output placement of an offload
+PLACE = "scilib.place"
+#: the guarded compute launch (and the split-precision check)
+SUBMIT = "scilib.submit"
+#: a call run on the host path, fallbacks included
+HOST_CALL = "scilib.host_call"
+#: one real move between the host and a device tier, per direction
+LINK_H2D = "scilib.link.h2d"
+LINK_D2H = "scilib.link.d2h"
+#: the blocked LU: a panel's trip to the host, its getf2 and the wait
+#: for its pivots; the row permutation; a block stored on the factor
+LU_PANEL = "scilib.lu.panel"
+LU_PERMUTE = "scilib.lu.permute"
+LU_STORE = "scilib.lu.store"
+#: a Python garbage-collection pause while a session is open
+HOST_GC = "scilib.host.gc"
+#: one span per solver driver call (``repro.solvers.drivers``)
+SOLVER_SPANS = {name: f"scilib.solver.{name}" for name in
+                ("getrf", "getrs", "gesv", "potrf", "potrs", "syev")}
+
+#: every span name the runtime emits
+SPANS = (DECIDE, PLACE, SUBMIT, HOST_CALL, LINK_H2D, LINK_D2H,
+         *SOLVER_SPANS.values(), LU_PANEL, LU_PERMUTE, LU_STORE, HOST_GC)
+
+span = jax.profiler.TraceAnnotation
+
+_GC_LOCK = threading.Lock()
+_GC_USERS = 0
+_GC_OPEN: list = []
+
+
+def _on_gc(phase: str, _info) -> None:
+    if phase == "start":
+        ann = span(HOST_GC)
+        ann.__enter__()
+        _GC_OPEN.append(ann)
+    elif _GC_OPEN:
+        _GC_OPEN.pop().__exit__(None, None, None)
+
+
+def gc_spans(on: bool) -> None:
+    """Mark garbage-collection pauses with :data:`HOST_GC` spans while
+    at least one caller has them on (refcounted: sessions turn them on
+    when they open and off when they close)."""
+    global _GC_USERS
+    with _GC_LOCK:
+        _GC_USERS = max(0, _GC_USERS + (1 if on else -1))
+        hooked = _on_gc in gc.callbacks
+        if _GC_USERS and not hooked:
+            gc.callbacks.append(_on_gc)
+        elif not _GC_USERS and hooked:
+            gc.callbacks.remove(_on_gc)
 
 # FLOP multipliers: complex arithmetic costs 4 real mul + 4 real add per
 # complex multiply-add -> 4x the real FLOP count at equal dimensions.
@@ -38,8 +106,10 @@ class BlasCall:
     :mod:`repro.core.callsite` (``routine@file:function:lineno``) — the
     per-site identity the paper's DBI patching keys on; ``seconds`` is
     the runtime's measured per-call wall time (dispatch/submission time
-    in async mode, device wall time under ``SCILIB_SYNC=1``).  Both
-    default empty/zero so older traces load unchanged.
+    in async mode, device wall time under ``SCILIB_SYNC=1``).  Of its
+    stages, ``decide_seconds`` (canonicalize + decide) precedes
+    ``seconds`` and ``place_seconds`` (operand and output placement) is
+    part of it.  All default empty/zero so older traces load unchanged.
 
     ``out_buf``/``out_nbytes`` identify the call's *output* buffer when
     it is a fresh allocation (no written operand to alias).  Offloaded
@@ -59,6 +129,8 @@ class BlasCall:
     devices: Tuple[int, ...] = ()    # device tier per scheduled tile
     callsite_id: str = ""            # per-site fingerprint (may be "")
     seconds: float = 0.0             # measured per-call wall time
+    decide_seconds: float = 0.0      # canonicalize + decide, before it
+    place_seconds: float = 0.0       # the placement part of ``seconds``
     out_buf: int = -1                # fresh-output buffer id (or -1)
     out_nbytes: int = 0              # its size (0 when out_buf is -1)
     # execution venue ("host"/"xla"/"pallas"); recorded only by

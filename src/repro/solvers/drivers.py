@@ -18,6 +18,7 @@ import jax
 
 from repro.core import lapack
 from repro.core import runtime as rtm
+from repro.core import trace as trc
 from repro.solvers import eigen as _eigen
 
 
@@ -34,15 +35,16 @@ def _resolve_nb(nb: int) -> int:
 
 @contextlib.contextmanager
 def _span(name: str, factor=None):
-    rt = rtm.active()
-    if rt is None:
-        yield None
-        return
-    span = rt.solver_begin(name, factor)
-    try:
-        yield span
-    finally:
-        rt.solver_end(span)
+    with trc.span(trc.SOLVER_SPANS[name]):
+        rt = rtm.active()
+        if rt is None:
+            yield None
+            return
+        span = rt.solver_begin(name, factor)
+        try:
+            yield span
+        finally:
+            rt.solver_end(span)
 
 
 # --------------------------------------------------------------------- #
