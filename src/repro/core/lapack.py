@@ -36,10 +36,11 @@ def _prec(dtype) -> str:
 def _note_panel(prec: str, m: int, nb: int, panel: jax.Array) -> None:
     """Report an unblocked panel factorization to the active runtime.
 
-    Panels are host-side getf2 work — they never offload, but inside a
-    solver span (repro.solvers) they count toward the span's panel
-    fraction and appear as ``getf2`` trace calls.  Outside a span this
-    is a no-op, keeping direct driver calls byte-identical to before."""
+    Panels are getf2 work on the factor's tier — inside a solver span
+    (repro.solvers) they count toward the span's panel fraction, as
+    offloaded or host calls by the factored panel's tier, and appear as
+    ``getf2`` trace calls.  Outside a span this is a no-op, keeping
+    direct driver calls byte-identical to before."""
     rt = rtm.active()
     if rt is not None:
         rt.note_panel(prec, m, nb, panel)
@@ -47,8 +48,8 @@ def _note_panel(prec: str, m: int, nb: int, panel: jax.Array) -> None:
 
 def _store(factor: jax.Array, idx, block: jax.Array) -> jax.Array:
     """``factor.at[idx].set(block)`` with the block first brought to the
-    factor's tier: a panel factored on the host, or an update the runtime
-    kept on the host, must not drag the whole factor off the device."""
+    factor's tier: an update the runtime kept on the host must not drag
+    the whole factor off the device."""
     with trc.span(trc.LU_STORE):
         return factor.at[idx].set(
             memspace.put(block, memspace.tier_of(factor), check=False))
@@ -87,12 +88,17 @@ def _getf2(p: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return jax.lax.fori_loop(0, nb, body, (p, piv0))
 
 
-def _pivot_panel(panel: jax.Array) -> Tuple[jax.Array, np.ndarray]:
-    """getf2 where LAPACK runs it, on the CPU: the panel is streamed to
-    the HOST tier and factored there.  Returns the factored panel, still
-    host-side, and the local pivot rows as numpy."""
-    fpanel, lpiv = _getf2(memspace.put(panel, memspace.HOST, check=False))
-    return fpanel, np.asarray(lpiv)
+def _pivot_panel(panel: jax.Array, tier: str
+                 ) -> Tuple[jax.Array, np.ndarray]:
+    """getf2 on the tier that holds the factor: a device-resident
+    factor's panel is factored on the device and stays there, and only
+    its ``nb`` local pivot rows come to the host; a host-resident
+    factor's panel is factored on the host.  Returns the factored panel,
+    on ``tier``, and the local pivot rows as numpy."""
+    fpanel, lpiv = _getf2(panel)
+    # the CPU backend hands a program's output back in its device memory
+    # kind whatever the input's; elsewhere the panel is already on tier
+    return memspace.put(fpanel, tier, check=False), memspace.pull(lpiv)
 
 
 def getrf(a: jax.Array, nb: int = DEFAULT_NB
@@ -107,13 +113,15 @@ def getrf(a: jax.Array, nb: int = DEFAULT_NB
     m, n = a.shape
     prec = _prec(a.dtype)
     k_max = min(m, n)
+    # every panel is factored where the caller's factor lives
+    tier = memspace.tier_of(a)
     lu = a
     piv = np.arange(m, dtype=np.int32)
     for j0 in range(0, k_max, nb):
         jb = min(nb, k_max - j0)
         panel = lu[j0:, j0:j0 + jb]
         with trc.span(trc.LU_PANEL):
-            fpanel, lpiv = _pivot_panel(panel)
+            fpanel, lpiv = _pivot_panel(panel, tier)
         _note_panel(prec, m - j0, jb, fpanel)
         # apply local pivots to the whole rows (left + right of panel)
         with trc.span(trc.LU_PERMUTE):

@@ -383,6 +383,16 @@ def put(x: jax.Array, tier: str, *, check: bool = True) -> jax.Array:
         raise _wrap_move_error(exc, device=None, nbytes=x.nbytes) from exc
 
 
+def pull(x) -> np.ndarray:
+    """``np.asarray(x)``, with the pull of a device-tier array to host
+    memory counted as a d2h link move.  The wait for whatever computes
+    ``x`` falls before the move, outside its span and its seconds."""
+    if not isinstance(x, jax.Array) or tier_of(x) == HOST:
+        return np.asarray(x)
+    x.block_until_ready()
+    return _link("d2h", x.nbytes, lambda: np.asarray(x))
+
+
 def put_block(x: jax.Array, device: int) -> jax.Array:
     """Re-home a buffer onto one specific DEVICE tier (tile scheduling).
 

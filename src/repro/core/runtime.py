@@ -292,7 +292,7 @@ class SolverStats:
 
     spans: int = 0               # solver_begin/solver_end pairs
     calls: int = 0               # inner BLAS + panel calls in the spans
-    panel_calls: int = 0         # unblocked getf2 panels (host work)
+    panel_calls: int = 0         # unblocked getf2 panels, on the factor's tier
     moved_bytes: int = 0         # movement attributed to the spans
     seconds: float = 0.0         # wall time inside the spans
 
@@ -323,7 +323,7 @@ class RuntimeStats:
     quarantines: int = 0       # breaker trips (incl. half-open re-trips)
     recoveries: int = 0        # quarantined devices re-admitted
     # moves over the host link, per direction, counted where memspace
-    # makes them (BLAS operands and the LU's panel round trips alike):
+    # makes them (BLAS operands and the LU's panel pivots alike):
     # bytes, and host seconds inside the transfer call
     link_h2d_bytes: int = 0
     link_h2d_seconds: float = 0.0
@@ -1008,11 +1008,13 @@ class OffloadRuntime:
             self._emit_event("solver_end", span.span_id, 0)
 
     def note_panel(self, prec: str, m: int, nb: int, a) -> None:
-        """Record one unblocked panel factorization (``getf2`` — the
-        host-side work inside a blocked driver).  Panels are recorded
-        only inside a solver span: outside the LAPACK tier the drivers
-        emit exactly the BLAS stream they always did, keeping
-        pre-solver traces and counters byte-identical."""
+        """Record one unblocked panel factorization (``getf2`` inside a
+        blocked driver), run on the tier of the factor it came from:
+        ``offloaded`` when the factored panel ``a`` is on a device tier,
+        ``on_host`` when it is on the host.  Panels are recorded only
+        inside a solver span: outside the LAPACK tier the drivers emit
+        exactly the BLAS stream they always did, keeping pre-solver
+        traces and counters byte-identical."""
         with self._lock:
             if not self._solver_stack:
                 return
@@ -1022,7 +1024,11 @@ class OffloadRuntime:
             sst.panel_calls += 1
             rst = self.stats.routine(f"{prec}getf2")
             rst.calls += 1
-            rst.on_host += 1
+            on_device = memspace.tier_of(a) == memspace.DEVICE
+            if on_device:
+                rst.offloaded += 1
+            else:
+                rst.on_host += 1
             if self.trace is not None:
                 bid = self._trace_id(a, "P")
                 el = a.dtype.itemsize
@@ -1030,6 +1036,7 @@ class OffloadRuntime:
                 self.trace.calls.append(BlasCall(
                     routine=f"{prec}getf2", m=m, n=nb, k=0,
                     operands=(("P", bid, m * nb * el, float(nb), True),),
+                    venue="xla" if on_device else "host",
                     solver_id=span.span_id))
 
     def resident_bytes(self) -> int:

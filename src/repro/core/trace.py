@@ -40,8 +40,9 @@ HOST_CALL = "scilib.host_call"
 #: one real move between the host and a device tier, per direction
 LINK_H2D = "scilib.link.h2d"
 LINK_D2H = "scilib.link.d2h"
-#: the blocked LU: a panel's trip to the host, its getf2 and the wait
-#: for its pivots; the row permutation; a block stored on the factor
+#: the blocked LU: a panel's getf2 on the factor's tier, the wait for
+#: it and its pivots' pull to the host; the row permutation; a block
+#: stored on the factor
 LU_PANEL = "scilib.lu.panel"
 LU_PERMUTE = "scilib.lu.permute"
 LU_STORE = "scilib.lu.store"
@@ -301,7 +302,8 @@ class Trace:
 
     def panel(self, prec: str, m: int, nb: int, a: int,
               solver: str = "") -> None:
-        """Unblocked LU panel factorization (getf2) — host-only work."""
+        """Unblocked LU panel factorization (getf2), on the tier of the
+        factor it came from."""
         el = _ELEM[prec]
         self.calls.append(BlasCall(
             routine=f"{prec}getf2", m=m, n=nb, k=0,

@@ -3,7 +3,6 @@ dispatch stage seconds, the flat counter dict, the report's link line,
 and the garbage-collection span hook."""
 import gc
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -69,20 +68,13 @@ def test_moves_outside_a_session_are_not_counted():
 
 
 @pytest.mark.parametrize("m,n,nb", [(96, 96, 32), (80, 64, 24)])
-def test_getrf_counts_its_panel_round_trips(monkeypatch, m, n, nb):
-    """Each panel goes to the host and, factored, comes back: the link
-    counters see 2 sum (m - j0) jb itemsize more than the BLAS rows."""
-    getf2 = lapack._getf2
-
-    def factored_on_host(p):
-        # as on a TPU host: getf2 runs on the host tier's device and its
-        # result stays there (the CPU backend would hand it back in the
-        # device memory kind)
-        f, piv = getf2(p)
-        return jax.device_put(f, memspace.active().host), piv
-
-    monkeypatch.setattr(lapack, "_getf2", factored_on_host)
+def test_getrf_counts_its_panel_round_trips(m, n, nb):
+    """A device-resident factor's panels are factored on the device and
+    stay there: the link counters see the BLAS rows' bytes and each
+    panel's int32 pivots coming to the host, no panel bytes, and every
+    panel counts as an offloaded getf2."""
     a = jnp.asarray(_f32((m, n)) + 4 * np.eye(m, n, dtype=np.float32))
+    assert memspace.tier_of(a) == memspace.DEVICE
     with repro.session(OffloadConfig(threshold=1, lapack=True)) as s:
         before = s.stats.flat()
         lu, piv = drivers.getrf(a, nb=nb)
@@ -91,14 +83,16 @@ def test_getrf_counts_its_panel_round_trips(monkeypatch, m, n, nb):
     diff = {k: after[k] - before.get(k, 0) for k in after}
     blas_bytes = sum(v for k, v in diff.items()
                      if k.endswith((".bytes_in", ".bytes_out")))
-    panels = sum((m - j0) * min(nb, min(m, n) - j0) * 4
-                 for j0 in range(0, min(m, n), nb))
-    assert diff["link_d2h_bytes"] == panels
-    assert diff["link_h2d_bytes"] == panels
-    assert (diff["link_h2d_bytes"] + diff["link_d2h_bytes"]
-            - blas_bytes) == 2 * panels
-    np.testing.assert_allclose(np.asarray(lu), np.asarray(
-        lapack.getrf(a, nb=nb)[0]), rtol=1e-6, atol=1e-6)
+    pivots = 4 * min(m, n)
+    assert diff["link_h2d_bytes"] + diff["link_d2h_bytes"] == (
+        blas_bytes + pivots)
+    panels = len(range(0, min(m, n), nb))
+    assert diff["sgetf2.calls"] == diff["sgetf2.offloaded"] == panels
+    assert diff["sgetf2.on_host"] == 0
+    ref_lu, ref_piv = lapack.getrf(a, nb=nb)
+    np.testing.assert_array_equal(np.asarray(piv), np.asarray(ref_piv))
+    np.testing.assert_allclose(np.asarray(lu), np.asarray(ref_lu),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_stage_seconds():

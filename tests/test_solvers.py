@@ -10,7 +10,7 @@ import pytest
 import scipy.linalg as sla
 
 import repro
-from repro.core import blas, lapack
+from repro.core import blas, lapack, memspace
 from repro.core import runtime as rtm
 from repro.core.config import OffloadConfig
 from repro.core.policy import host_array
@@ -78,6 +78,45 @@ def test_getrf_rectangular_and_partial_blocks(shape, nb):
     up = np.triu(np.asarray(lu)[:k, :])
     np.testing.assert_allclose(np.asarray(a)[np.asarray(piv)],
                                low @ up, atol=1e-10)
+
+
+@pytest.mark.parametrize("dtype", ("float32", "complex64"))
+@pytest.mark.parametrize("tier", (memspace.HOST, memspace.DEVICE))
+def test_getrf_factors_each_panel_on_the_factors_tier(monkeypatch, dtype,
+                                                      tier):
+    """The panel runs where the factor lives: a host factor's panels are
+    host getf2 calls, a device factor's offloaded ones, each factored
+    panel on the factor's tier; both give the same pivots and factors."""
+    n, nb = 96, 32
+    a = _rand((n, n), dtype)
+    src = memspace.put(jnp.asarray(a), tier)
+    assert memspace.tier_of(src) == tier
+    panels = []
+
+    def spy(panel, tier, factor=lapack._pivot_panel):
+        fpanel, lpiv = factor(panel, tier)
+        panels.append((memspace.tier_of(fpanel), lpiv))
+        return fpanel, lpiv
+
+    monkeypatch.setattr(lapack, "_pivot_panel", spy)
+    with repro.session(OffloadConfig(threshold=1, lapack=True)) as s:
+        lu, piv = drivers.getrf(src, nb=nb)
+        st = s.stats.per_routine[f"{lapack._prec(dtype)}getf2"]
+    count = n // nb
+    assert [t for t, _ in panels] == [tier] * count
+    # the matrix is random: every panel swaps rows
+    assert all((lpiv != np.arange(nb)).any() for _, lpiv in panels)
+    assert st.calls == count
+    assert (st.offloaded, st.on_host) == (
+        (count, 0) if tier == memspace.DEVICE else (0, count))
+    piv, lu = np.asarray(piv), np.asarray(lu)
+    low = np.tril(lu, -1) + np.eye(n)
+    np.testing.assert_allclose(a[piv], low @ np.triu(lu),
+                               rtol=1e-4, atol=1e-4)
+    other = memspace.HOST if tier == memspace.DEVICE else memspace.DEVICE
+    lu2, piv2 = lapack.getrf(memspace.put(jnp.asarray(a), other), nb=nb)
+    np.testing.assert_array_equal(piv, np.asarray(piv2))
+    np.testing.assert_allclose(lu, np.asarray(lu2), rtol=1e-5, atol=1e-5)
 
 
 # --------------------------------------------------------------------- #
